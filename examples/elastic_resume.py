@@ -2,7 +2,9 @@
 
 Block-based checkpoints make recovery onto a DIFFERENT device count a
 metadata remap (DESIGN.md §3): this script spawns the two phases as
-subprocesses with different forced host-device counts.
+subprocesses with different forced host-device counts.  The children
+run on the CPU backend: the forced device count is a CPU-only flag, and
+on a machine with a TPU two processes cannot share the chip.
 
     PYTHONPATH=src python examples/elastic_resume.py
 """
@@ -33,7 +35,8 @@ def run_phase(ndev, mp, steps, ckpt):
     prog = PHASE.format(ndev=ndev, mp=mp, steps=steps, ckpt=ckpt,
                         src=os.path.join(ROOT, "src"))
     r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                       text=True, cwd=ROOT)
+                       text=True, cwd=ROOT,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
     if r.returncode != 0:
         print(r.stderr[-2000:])
         raise SystemExit(1)

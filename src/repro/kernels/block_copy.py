@@ -27,6 +27,14 @@ def _copy_kernel(src_ref, dst_ref, pool_ref, out_ref):
     out_ref[...] = pool_ref[...]
 
 
+def _dma_copy_kernel(src_ref, dst_ref, pool_ref, out_ref, sem):
+    i = pl.program_id(0)
+    copy = pltpu.make_async_copy(pool_ref.at[src_ref[i]],
+                                 out_ref.at[dst_ref[i]], sem)
+    copy.start()
+    copy.wait()
+
+
 def _gather_kernel(idx_ref, pool_ref, out_ref):
     out_ref[...] = pool_ref[...]
 
@@ -39,29 +47,30 @@ def block_copy(pool: jax.Array, src: jax.Array, dst: jax.Array,
                *, interpret: bool = False) -> jax.Array:
     """pool: (NB, *block); src/dst: (n,) int32 -> pool with plan applied.
 
-    Grid step i DMAs block ``src[i]`` into position ``dst[i]``; untouched
-    blocks are pre-seeded by aliasing the input (donate) or, in this
-    functional form, by a first pass-through write.
+    Grid step i DMAs block ``src[i]`` into position ``dst[i]`` straight
+    from HBM to HBM: a flat pool's block is one whole sequence state
+    (52 MB for Mamba2-370M), far beyond what VMEM staging can hold.
+    Output aliases the pool, so untouched blocks stay in place.  On TPU
+    each block must be one contiguous tile run: the pool is laid out
+    ``(NB, 1, E)`` with E a multiple of 128 (see
+    ``serve.arch.ConstantStateManager``); Mosaic refuses a single row of
+    an ``(NB, E)`` array, whose (8, 128) tiles interleave 8 rows.
     """
     n = src.shape[0]
-    blk = pool.shape[1:]
-    ones = (1,) + blk
-    zeros = tuple(0 for _ in blk)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n,),
-        in_specs=[pl.BlockSpec(ones, lambda i, s, d: (s[i],) + zeros)],
-        out_specs=pl.BlockSpec(ones, lambda i, s, d: (d[i],) + zeros),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
     )
-    moved = pl.pallas_call(
-        _copy_kernel,
+    return pl.pallas_call(
+        _dma_copy_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         interpret=interpret,
         input_output_aliases={2: 0},
     )(src, dst, pool)
-    return moved
 
 
 def gather_blocks(pool: jax.Array, idx: jax.Array,

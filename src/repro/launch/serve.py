@@ -26,6 +26,7 @@ import time
 import jax
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
 from repro.serve.engine import Engine
 from repro.serve.scheduler import FairAdmission
@@ -78,6 +79,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -226,6 +226,13 @@ class TransferStats:
     #: compute mark, refreshed on every ``note_compute()``.
     python_launches: int = 0
     dispatches_per_step: float = 0.0
+    #: block-copy kernel calls by kernel name, one per stream copied
+    #: (``gather_blocks`` / ``scatter_blocks`` / ``copy_pool_blocks`` /
+    #: ``block_copy``); flat pools swap through plain jnp indexing
+    kernel_launches: Dict[str, int] = dataclasses.field(default_factory=dict)
+
+    def count_kernel(self, name: str, calls: int) -> None:
+        self.kernel_launches[name] = self.kernel_launches.get(name, 0) + calls
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -838,6 +845,7 @@ class QueueSet:
         streams, set_, layered = self._streams(batch[0].pool_class)
         copy = ops.copy_pool_blocks if layered else ops.block_copy
         set_([copy(s, src, dst) for s in streams])
+        self.stats.count_kernel(copy.__name__, len(streams))
         self.stats.launches += 1
         self.stats.coalesced += len(batch) - 1
         self.arena.allocator(batch[0].pool_class).note_write(
@@ -862,6 +870,8 @@ class QueueSet:
         streams, _, layered = self._streams(batch[0].pool_class)
         gathered = [ops.gather_blocks(s, ids) if layered else s[ids]
                     for s in streams]
+        if layered:
+            self.stats.count_kernel("gather_blocks", len(streams))
         self.stats.launches += 1
         self.stats.coalesced += len(batch) - 1
         off = 0
@@ -931,6 +941,9 @@ class QueueSet:
                else s.at[idx].set(jnp.asarray(h))
                for s, h in zip(streams, payload)]
         set_(out)
+        if layered:
+            self.stats.count_kernel(
+                "scatter_blocks", sum(h is not None for h in payload))
         self.arena.allocator(cls).note_write(
             [int(b) for b in np.asarray(plan.dst)])
         plan.nbytes = int(sum(h.nbytes for h in payload if h is not None))

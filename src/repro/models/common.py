@@ -42,6 +42,22 @@ def dense_init(rng, d_in: int, d_out: int, dtype, *, scale: Optional[float] = No
     return _init(rng, (d_in, d_out), scale, dtype)
 
 
+def jit_init(init_fn: Callable, rng) -> Tuple[Params, AxTree]:
+    """``init_fn(rng) -> (params, axes)`` with the params built in ONE
+    jit, so XLA writes each tensor in its final dtype.  Run eagerly,
+    ``_init`` first materialises a whole stacked tensor in float32: 4 GB
+    for MiniCPM3-4B's (62, 2560, 6400) MLP stack, beside 8 GB of bf16
+    parameters on a 16 GB chip.  Same values as the eager init."""
+    cell = {}
+
+    def params_only(r):
+        p, cell["ax"] = init_fn(r)
+        return p
+
+    params = jax.jit(params_only)(rng)
+    return params, cell["ax"]
+
+
 def stacked(init_fn: Callable, rng, num: int, *args, **kw):
     """vmap an initializer over a leading stack axis (layers)."""
     rngs = jax.random.split(rng, num)

@@ -27,7 +27,7 @@ from repro.models import attention as A
 from repro.models import moe as MOE
 from repro.models.moe_sharded import moe_ffn_dispatch
 from repro.models.common import (AxTree, Params, chunked_lm_loss, dense_init,
-                                 init_mlp, mlp, rmsnorm, stacked)
+                                 init_mlp, jit_init, mlp, rmsnorm, stacked)
 
 _NEG = -1e30
 
@@ -106,6 +106,9 @@ class DecoderLM:
         return p, ax
 
     def init(self, rng) -> Tuple[Params, AxTree]:
+        return jit_init(self._init_params, rng)
+
+    def _init_params(self, rng) -> Tuple[Params, AxTree]:
         cfg = self.cfg
         r = jax.random.split(rng, 5)
         p: Params = {"embed": dense_init(r[0], cfg.vocab_size, cfg.d_model,

@@ -24,9 +24,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.launch import shardings as SH

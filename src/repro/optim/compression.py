@@ -7,9 +7,8 @@ per-block scale (block = the paper's fixed-size quantum: 8192 f32 values
 so the bias cancels across steps (1-bit Adam lineage).
 
 Usage: the compressed train step (train/compressed.py) computes
-per-device gradients inside ``repro.compat.shard_map`` (the version-
-portable spelling) over the data axes and calls ``sync_mean`` instead of
-``psum``:
+per-device gradients inside ``jax.shard_map`` over the data axes and
+calls ``sync_mean`` instead of ``psum``:
 
   1. add residual to the local gradient,
   2. quantize to int8 + f32 per-block scales,

@@ -66,7 +66,7 @@ class ConstantStateManager:
     The SSM/linear-attention analogue of ``PagedKVManager``: every
     sequence owns exactly ONE block of ``state_elems`` float32 elements
     (the flattened recurrent state), allocated at admission and never
-    grown.  The device stream is a flat ``(num_blocks, state_elems)``
+    grown.  The device stream is a flat ``(num_blocks, 1, state_elems)``
     pool registered with the transfer plane (``layered=False``), so
     swap-out/swap-in/prefetch/compaction all ride the same plans and
     kernels as paged KV -- one block per sequence just makes every move
@@ -82,7 +82,10 @@ class ConstantStateManager:
         self.pool_class = arena.register_class(
             pool_class, num_blocks=num_blocks,
             block_shape=(state_elems,), dtype=np.float32)
-        self.pool = jnp.zeros((num_blocks, state_elems), jnp.float32)
+        # (NB, 1, E), not (NB, E): on TPU a 2-D array's (8, 128) tiles
+        # interleave 8 rows, so one block would not be contiguous and
+        # block_copy could not move it as one DMA
+        self.pool = jnp.zeros((num_blocks, 1, state_elems), jnp.float32)
         self._maps: Dict[int, Mapping] = {}
         arena.transfers.register_executor(
             self.pool_class, self._streams, self._set_streams,
@@ -920,11 +923,11 @@ class ConstantStateStrategy(CacheStrategy):
 
     def decode(self, params, tokens):
         idx = jnp.asarray(self._rows, jnp.int32)
-        state = self.model.rows_to_state(self.mgr.pool[idx])
+        state = self.model.rows_to_state(self.mgr.pool[idx, 0])
         logits, new_state = self.model.decode_step(params, tokens, state)
         # scatter back every step: the pool stays authoritative, so a
         # later swap-out gather always reads the current state
-        self.mgr.pool = self.mgr.pool.at[idx].set(
+        self.mgr.pool = self.mgr.pool.at[idx, 0].set(
             self.model.state_to_rows(new_state))
         return logits
 
@@ -958,9 +961,9 @@ class ConstantStateStrategy(CacheStrategy):
 
             def impl(p, tokens, pool, rows, upd_slots, upd_rows):
                 rows = rows.at[upd_slots].set(upd_rows, mode="drop")
-                state = model.rows_to_state(pool[rows])
+                state = model.rows_to_state(pool[rows, 0])
                 logits, new_state = model.decode_step(p, tokens, state)
-                pool = pool.at[rows].set(model.state_to_rows(new_state))
+                pool = pool.at[rows, 0].set(model.state_to_rows(new_state))
                 return jnp.argmax(logits, axis=-1), pool, rows
 
             self._fused = jax.jit(impl, donate_argnums=(2,))
@@ -993,7 +996,7 @@ class ConstantStateStrategy(CacheStrategy):
         nxt = np.asarray(jnp.argmax(last, axis=-1))   # forces completion
         idx = jnp.asarray([self.mgr.row(req.rid) for _, req, _ in batch],
                           jnp.int32)
-        self.mgr.pool = self.mgr.pool.at[idx].set(
+        self.mgr.pool = self.mgr.pool.at[idx, 0].set(
             self.model.state_to_rows(state))
         return nxt, sum(lens)
 
@@ -1198,10 +1201,10 @@ class CompositeStrategy(CacheStrategy):
     def decode(self, params, tokens):
         from repro.models.zamba2 import ZambaState
         idx = jnp.asarray(self._rows, jnp.int32)
-        conv, ssd = self.model.rows_to_state(self.state_mgr.pool[idx])
+        conv, ssd = self.model.rows_to_state(self.state_mgr.pool[idx, 0])
         state = ZambaState(conv, ssd, self.cache)
         logits, new_state = self.model.decode_step(params, tokens, state)
-        self.state_mgr.pool = self.state_mgr.pool.at[idx].set(
+        self.state_mgr.pool = self.state_mgr.pool.at[idx, 0].set(
             self.model.state_to_rows(new_state.conv, new_state.ssd))
         # carry the advanced seq_lens forward too (PagedKVStrategy keeps
         # the whole returned cache): between steps the device lens must
@@ -1267,10 +1270,10 @@ class CompositeStrategy(CacheStrategy):
                 rows = rows.at[upd_slots].set(upd_rows, mode="drop")
                 cache = dataclasses.replace(cache, block_tables=tables,
                                             seq_lens=lens)
-                conv, ssd = model.rows_to_state(pool[rows])
+                conv, ssd = model.rows_to_state(pool[rows, 0])
                 logits, st = model.decode_step(
                     p, tokens, ZambaState(conv, ssd, cache))
-                pool = pool.at[rows].set(
+                pool = pool.at[rows, 0].set(
                     model.state_to_rows(st.conv, st.ssd))
                 return jnp.argmax(logits, axis=-1), st.kv, pool, rows
 
@@ -1322,7 +1325,7 @@ class CompositeStrategy(CacheStrategy):
                                          v_pool=state.kv.v_pool)
         idx = jnp.asarray([self.state_mgr.row(req.rid)
                            for _, req, _ in batch], jnp.int32)
-        self.state_mgr.pool = self.state_mgr.pool.at[idx].set(
+        self.state_mgr.pool = self.state_mgr.pool.at[idx, 0].set(
             self.model.state_to_rows(state.conv, state.ssd))
         return nxt, sum(lens)
 

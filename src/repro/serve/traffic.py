@@ -152,12 +152,15 @@ def _gaps(kind: str, n: int, mean_gap: float,
 def make_trace(kind: str, n: int, vocab: int, *, seed: int = 0,
                mean_gap: float = 2.0, tenants: int = 1,
                max_new: int = 8, prompt_cap: int = 24,
+               prompt_min: int = 4,
                shared_frac: float = 0.0, n_bases: int = 2,
                deadline_slack: Optional[float] = None,
                priority_classes: Optional[Sequence[int]] = None
                ) -> RequestSource:
     """Seeded, replayable arrival trace (see module docstring).
 
+    Unshared prompts draw ``prompt_min <= len < prompt_cap`` tokens; a
+    shared one is a base from that range plus up to 5 extra tokens.
     ``deadline_slack`` (in decode-steps per owed token) sets
     ``deadline = arrival + slack * max_new``; ``priority_classes``
     cycles the given classes across requests.  Same seed, same trace --
@@ -170,7 +173,8 @@ def make_trace(kind: str, n: int, vocab: int, *, seed: int = 0,
     rng = np.random.RandomState(seed)
     gaps = _gaps(kind, n, mean_gap, rng)
     bases = [rng.randint(2, vocab, size=int(rng.randint(
-        max(4, prompt_cap // 2), prompt_cap))) for _ in range(n_bases)]
+        max(prompt_min, prompt_cap // 2), prompt_cap)))
+             for _ in range(n_bases)]
     reqs: List[Request] = []
     t = 0.0
     for i in range(n):
@@ -183,7 +187,8 @@ def make_trace(kind: str, n: int, vocab: int, *, seed: int = 0,
                       if extra else base.copy())
         else:
             prompt = rng.randint(2, vocab,
-                                 size=int(rng.randint(4, prompt_cap)))
+                                 size=int(rng.randint(prompt_min,
+                                                      prompt_cap)))
         reqs.append(Request(
             rid=i, prompt=prompt, max_new=max_new,
             tenant=f"tenant{i % max(1, tenants)}",

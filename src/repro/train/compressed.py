@@ -29,9 +29,9 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.launch import shardings as SH
 from repro.launch.mesh import batch_axes
 from repro.launch.steps import Step, opt_shardings, rules_for, _ns

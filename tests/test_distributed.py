@@ -117,10 +117,10 @@ def test_serve_step_parity_sharded():
 
 _COMPRESSION = """
 from repro.optim import compression as C
-from repro.compat import shard_map
+from jax import shard_map
 import functools
-from jax.sharding import PartitionSpec as P
-mesh = jax.make_mesh((4,), ("data",))
+from jax.sharding import AxisType, PartitionSpec as P
+mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
 rng = np.random.RandomState(0)
 # per-device gradients: (4, L) -- each row one device's gradient
 g = rng.randn(4, C.BLOCK * 2).astype(np.float32)

@@ -147,6 +147,34 @@ def test_resident_identity_across_external_compaction(families, rng):
     assert got[True] == got[False]
 
 
+def test_constant_state_compaction_rides_block_copy(families):
+    """``compact_now()`` on the flat ``(NB, 1, E)`` state pool moves
+    whole sequence states with the ``block_copy`` kernel, and every
+    sequence decodes exactly as it does without the move."""
+    cfg, model, params = families["ssm"]
+    prompts = _prompts(cfg, seed=11, n=3, shared=False)
+    max_new = [2, 8, 8]          # the early finisher leaves a hole
+    got = {}
+    for compact in (False, True):
+        eng = _engine(model, params, True, slots=3)
+        for i, pr in enumerate(prompts):
+            eng.submit(Request(rid=i, prompt=pr.copy(), max_new=max_new[i]))
+        moved = 0
+        while (eng.sched.has_work or eng.running) and eng.steps < 100:
+            eng.step()
+            eng.check_consistency()
+            if compact and eng.done and not moved:
+                moved = eng.compact_now()
+                assert moved > 0
+                eng.check_consistency()
+        launches = eng.stats["transfers"]["kernel_launches"]
+        assert launches.get("block_copy", 0) >= int(compact), launches
+        assert "copy_pool_blocks" not in launches
+        got[compact] = {r.rid: list(r.generated) for r in eng.done}
+        assert_engine_quiescent(eng)
+    assert got[True] == got[False]
+
+
 def test_resident_migrate_live_token_identity(families, tmp_path):
     """Live migration restores into a FRESH engine whose resident
     tables have never seen these requests: the adoption path must mark
