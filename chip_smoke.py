@@ -47,6 +47,7 @@ from repro.launch.steps import build_serve_step  # noqa: E402
 from repro.models.api import build_model  # noqa: E402
 from repro.models.lm import DecoderLM  # noqa: E402
 from repro.serve.engine import Engine  # noqa: E402
+from repro.serve.telemetry import compile_totals  # noqa: E402
 from repro.serve.traffic import make_trace  # noqa: E402
 
 SEED = 0
@@ -74,31 +75,6 @@ def require_tpu() -> None:
     if platform != "tpu":
         sys.exit(f"chip_smoke: JAX found platform {platform!r}, not 'tpu'; "
                  f"this smoke run needs a TPU chip and has no CPU fallback")
-
-
-class CompileMeter:
-    """Backend compile seconds and persistent-cache hits, from JAX's
-    monitoring events (a cache hit records its retrieval time as the
-    compile event)."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, duration: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.compiles += 1
-
-    def _event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def snapshot(self):
-        return self.seconds, self.compiles, self.cache_hits
 
 
 def peak_bytes() -> int:
@@ -227,7 +203,7 @@ MAMBA2 = ServePhase(
     kernels=("block_copy",))
 
 
-def serve_phase(phase: ServePhase, cfg, meter: CompileMeter) -> None:
+def serve_phase(phase: ServePhase, cfg) -> None:
     """Build ``cfg``, serve the phase's trace through ``Engine.serve``
     with one forced compaction after the first retirement, and check
     every served token against the one-at-a-time reference."""
@@ -248,7 +224,7 @@ def serve_phase(phase: ServePhase, cfg, meter: CompileMeter) -> None:
                         prompt_min=phase.prompt_min,
                         prompt_cap=phase.prompt_cap,
                         shared_frac=phase.shared_frac, n_bases=1)
-    c0 = meter.snapshot()
+    c0 = compile_totals()
     t0 = time.perf_counter()
     # serve step by step until the first retirement, compact once, then
     # serve the rest of the trace
@@ -257,7 +233,7 @@ def serve_phase(phase: ServePhase, cfg, meter: CompileMeter) -> None:
     moved = eng.compact_now()
     done = eng.serve(source)
     wall = time.perf_counter() - t0
-    c1 = meter.snapshot()
+    c1 = compile_totals()
 
     st = eng.stats
     tokens = sum(len(r.generated) for r in done)
@@ -265,8 +241,9 @@ def serve_phase(phase: ServePhase, cfg, meter: CompileMeter) -> None:
     log(f"{name}: served {len(done)}/{phase.requests} requests, {tokens} "
         f"tokens in {eng.steps} steps; wall {wall:.2f} s including compiles "
         f"({tokens / wall:.2f} tokens/s, smoke run, not a measurement)")
-    log(f"{name}: compile {c1[0] - c0[0]:.2f} s over {c1[1] - c0[1]} "
-        f"compiles, {c1[2] - c0[2]} persistent-cache hits")
+    log(f"{name}: compile {c1[1] - c0[1]:.2f} s over {c1[0] - c0[0]} "
+        f"compiles, {c1[2] - c0[2]} persistent-cache hits; in the step "
+        f"loop's prefill {eng.telemetry.totals('serve.prefill').compiles}")
     log(f"{name}: preemptions {st['preemptions']}, COW copies "
         f"{st['cow_copies']}, prefix hits {st['prefix_hits']}, "
         f"compactions {st['compactions']} ({st['blocks_compacted']} blocks; "
@@ -370,15 +347,15 @@ def main(argv=None) -> int:
               "kind": devices[0].device_kind, "count": len(devices)}
     log(f"smoke run (not a benchmark) on {device['count']} x "
         f"{device['kind']}; compile cache {enable_compile_cache()}")
-    meter = CompileMeter()
+    compile_totals()                 # count from here
     t0 = time.perf_counter()
     if args.four_chips:
         four_chip_phase(get_config(MINICPM3.arch))
     else:
-        serve_phase(MINICPM3, get_config(MINICPM3.arch), meter)
+        serve_phase(MINICPM3, get_config(MINICPM3.arch))
         log(f"peak bytes in use after {MINICPM3.arch}: {peak_bytes()}")
-        serve_phase(MAMBA2, get_config(MAMBA2.arch), meter)
-    seconds, compiles, hits = meter.snapshot()
+        serve_phase(MAMBA2, get_config(MAMBA2.arch))
+    compiles, seconds, hits = compile_totals()
     log(f"total: compile {seconds:.2f} s over {compiles} compiles "
         f"({hits} persistent-cache hits); wall "
         f"{time.perf_counter() - t0:.2f} s; peak bytes in use "

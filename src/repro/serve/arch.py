@@ -395,6 +395,17 @@ class CacheStrategy:
         warm jit trace (pad entries index ``slots`` -> scatter-dropped)."""
         return min(1 << (n - 1).bit_length(), slots) if n else 0
 
+    @property
+    def prefill_quantum(self) -> int:
+        """What a padded prefill rounds its longest row up to."""
+        return self._pad
+
+    def prefill_width(self, n: int) -> int:
+        """Padded length of a prefill whose longest row holds ``n``
+        tokens."""
+        q = self.prefill_quantum
+        return -(-n // q) * q
+
     def full_sync_cost(self) -> Tuple[int, int]:
         """(rows, bytes) one full-rebuild sync uploads -- the eager
         fallback's per-step cost, reported beside the delta path's."""
@@ -515,6 +526,10 @@ class PagedKVStrategy(CacheStrategy):
     @property
     def block_tokens(self) -> int:
         return self.cache.config.block_tokens
+
+    @property
+    def prefill_quantum(self) -> int:
+        return self.block_tokens
 
     @property
     def utilization(self) -> float:
@@ -662,7 +677,7 @@ class PagedKVStrategy(CacheStrategy):
         cfg = self.cache.config
         bt = cfg.block_tokens
         lens = [req.tokens_held for _, req, _ in batch]
-        S = -(-max(lens) // bt) * bt
+        S = self.prefill_width(max(lens))
         toks = np.zeros((len(batch), S), np.int64)
         tables = np.full((len(batch), cfg.max_blocks_per_seq), self.sink,
                          np.int32)
@@ -959,14 +974,16 @@ class ConstantStateStrategy(CacheStrategy):
         if self._fused is None:
             model = self.model
 
-            def impl(p, tokens, pool, rows, upd_slots, upd_rows):
+            def constant_state_decode(p, tokens, pool, rows, upd_slots,
+                                      upd_rows):
                 rows = rows.at[upd_slots].set(upd_rows, mode="drop")
                 state = model.rows_to_state(pool[rows, 0])
                 logits, new_state = model.decode_step(p, tokens, state)
                 pool = pool.at[rows, 0].set(model.state_to_rows(new_state))
                 return jnp.argmax(logits, axis=-1), pool, rows
 
-            self._fused = jax.jit(impl, donate_argnums=(2,))
+            self._fused = jax.jit(constant_state_decode,
+                                  donate_argnums=(2,))
         return self._fused
 
     def decode_resident(self, params, tokens):
@@ -983,7 +1000,7 @@ class ConstantStateStrategy(CacheStrategy):
         right padding out of the SSM scan exactly, so this is
         token-identical to per-sequence prefill."""
         lens = [req.tokens_held for _, req, _ in batch]
-        S = -(-max(lens) // self._pad) * self._pad
+        S = self.prefill_width(max(lens))
         toks = np.zeros((len(batch), S), np.int64)
         for row, (_, req, _) in enumerate(batch):
             toks[row, : lens[row]] = np.concatenate(
@@ -1261,8 +1278,8 @@ class CompositeStrategy(CacheStrategy):
             from repro.models.zamba2 import ZambaState
             model = self.model
 
-            def impl(p, tokens, cache, pool, rows, upd_slots, upd_tables,
-                     upd_lens, upd_rows):
+            def composite_decode(p, tokens, cache, pool, rows, upd_slots,
+                                 upd_tables, upd_lens, upd_rows):
                 tables = cache.block_tables.at[upd_slots].set(
                     upd_tables, mode="drop")
                 lens = cache.seq_lens.at[upd_slots].set(upd_lens,
@@ -1277,7 +1294,7 @@ class CompositeStrategy(CacheStrategy):
                     model.state_to_rows(st.conv, st.ssd))
                 return jnp.argmax(logits, axis=-1), st.kv, pool, rows
 
-            self._fused = jax.jit(impl, donate_argnums=(2, 3))
+            self._fused = jax.jit(composite_decode, donate_argnums=(2, 3))
         return self._fused
 
     def decode_resident(self, params, tokens):
@@ -1301,7 +1318,7 @@ class CompositeStrategy(CacheStrategy):
         from repro.models.zamba2 import ZambaState
         cfg = self.cache.config
         lens = [req.tokens_held for _, req, _ in batch]
-        S = -(-max(lens) // self._pad) * self._pad
+        S = self.prefill_width(max(lens))
         toks = np.zeros((len(batch), S), np.int64)
         tables = np.full((len(batch), cfg.max_blocks_per_seq), self.sink,
                          np.int32)

@@ -84,13 +84,8 @@ class PrefillWorker:
         released back to its own pool by the export."""
         eng = self.engine
         eng.strategy.admit(req.rid, len(req.prompt), req.tenant)
-        t0 = time.perf_counter()
-        nxt, billed = eng.strategy.prefill(eng.params, [(0, req, 0)])
-        t1 = time.perf_counter()
-        eng.sched.observe_prefill(billed, t1 - t0)
-        eng.prefill_tokens += billed
-        if req.t_first < 0:
-            req.t_first = t1       # first token IS the prefill's argmax
+        # timed, billed and stamped as the step loop's admissions are
+        nxt = eng.prefill([(0, req, 0)])
         bundles = [export_mapping(eng.arena, mgr.disown(req.rid))
                    for mgr in _managers(eng.strategy)]
         self.prefills += 1

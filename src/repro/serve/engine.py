@@ -66,6 +66,7 @@ import numpy as np
 from repro.mem import BACKGROUND, URGENT, Arena, LeaseRevokedError
 from repro.serve.arch import build_strategy
 from repro.serve.scheduler import Request, Scheduler
+from repro.serve.telemetry import Telemetry
 
 __all__ = ["Engine", "Request"]
 
@@ -172,8 +173,8 @@ class Engine:
         self.host_uploads = 0          # step tails with any h2d upload
         self.table_sync_bytes = 0
         self.table_rows_updated = 0
-        self.phase_time = {"dispatch": 0.0, "sync": 0.0, "decode": 0.0,
-                           "retire": 0.0}
+        # spans of the step loop and the compiles charged to them
+        self.telemetry = Telemetry()
         self.running: Dict[int, Request] = {}   # slot -> req
         self.done: List[Request] = []
         self._prefix_map: Dict[Tuple[int, bytes], List[int]] = {}
@@ -386,9 +387,9 @@ class Engine:
             else:
                 batch.append((slot, req, shared))
         if batch:
-            self._batched_prefill(batch)
+            self._latch(batch, self.prefill(batch))
         if suffix:
-            self._suffix_prefill(suffix)
+            self._latch(suffix, self._suffix_prefill(suffix))
 
     def _slot_of(self, rid: int) -> int:
         for slot, req in self.running.items():
@@ -407,40 +408,59 @@ class Engine:
         self.strategy.mark_dirty(slot)
         self._tok_dirty = True
 
-    def _batched_prefill(self, batch: List[Tuple[int, Request, int]]) -> None:
-        """ONE padded prefill call for all of this step's admissions.
+    def prefill(self, batch: List[Tuple[int, Request, int]]) -> np.ndarray:
+        """ONE padded prefill call for a batch of placed ``(slot,
+        request, shared)`` rows; returns each row's next token.
 
         The strategy owns padding, table/row construction and the KV or
         state writes; the engine owns the clock and the billing: the
         scheduler's admission budget EWMA sees the tokens the strategy
-        actually computed, and TTFT ends at the prefill's argmax."""
-        t0 = time.perf_counter()
-        nxt, billed = self.strategy.prefill(self.params, batch)
-        t1 = time.perf_counter()
-        self.sched.observe_prefill(billed, t1 - t0)
-        for row, (slot, req, _) in enumerate(batch):
-            self._next_tok[slot] = nxt[row]
-            if req.t_first < 0:
-                # the first token IS the prefill's argmax: TTFT ends here
-                req.t_first = t1
+        actually computed, and TTFT ends at the prefill's argmax.  The
+        step loop's admissions and ``PrefillWorker`` both prefill here."""
+        with self.telemetry.span("serve.prefill") as sp:
+            if sp.recording:
+                lens = [req.tokens_held for _, req, _ in batch]
+                sp.set(seqs=len(batch), tokens=sum(lens),
+                       padded=len(batch)
+                       * self.strategy.prefill_width(max(lens)))
+            nxt, billed = self.strategy.prefill(self.params, batch)
+        self.sched.observe_prefill(billed, sp.seconds)
+        self._stamp(batch, sp)
         self.prefill_tokens += billed
+        return nxt
 
-    def _suffix_prefill(self, batch: List[Tuple[int, Request, int]]) -> None:
+    def _suffix_prefill(self, batch: List[Tuple[int, Request, int]]
+                        ) -> np.ndarray:
         """ONE padded suffix-only prefill call for this step's forked
-        admissions.  Bills ONLY the suffix: the admission budget's EWMA
-        and the token counters see the work actually done, and the
-        skipped prefix is the headline savings metric."""
-        t0 = time.perf_counter()
-        nxt, suffix_tokens, saved = self.strategy.prefill_suffix(
-            self.params, batch)
-        t1 = time.perf_counter()
-        self.sched.observe_prefill(suffix_tokens, t1 - t0)
-        for row, (slot, req, _) in enumerate(batch):
-            self._next_tok[slot] = nxt[row]
-            if req.t_first < 0:
-                req.t_first = t1
+        admissions; returns each row's next token.  Bills ONLY the
+        suffix: the admission budget's EWMA and the token counters see
+        the work actually done, and the skipped prefix is the headline
+        savings metric."""
+        with self.telemetry.span("serve.prefill") as sp:
+            nxt, suffix_tokens, saved = self.strategy.prefill_suffix(
+                self.params, batch)
+            sp.set(seqs=len(batch), tokens=suffix_tokens)
+        self.sched.observe_prefill(suffix_tokens, sp.seconds)
+        self._stamp(batch, sp)
         self.prefill_tokens += suffix_tokens
         self.prefill_tokens_saved += saved
+        return nxt
+
+    @staticmethod
+    def _stamp(batch, sp) -> None:
+        """Stamp a prefill's requests: admitted at the prefill's start
+        (once, not again on a resume), first token at its end (the
+        argmax)."""
+        for _, req, _ in batch:
+            if req.t_admit < 0:
+                req.t_admit = sp.t0
+            if req.t_first < 0:
+                req.t_first = sp.t1
+
+    def _latch(self, batch, nxt: np.ndarray) -> None:
+        """Each prefilled row's next token is its slot's next input."""
+        for row, (slot, _, _) in enumerate(batch):
+            self._next_tok[slot] = nxt[row]
 
     # ---------------- preemption / swap-out ----------------
     def _preempt_slot(self, slot: int) -> None:
@@ -647,81 +667,104 @@ class Engine:
         the LIFO resume candidate's speculative swap-in enqueues and
         launches on the background h2d lane, overlapping the decode
         too.
+
+        Each part runs inside a ``serve.*`` span of ``self.telemetry``
+        (step, fence, compact, admit with its prefill, grow, transfer,
+        sync, decode, retire); ``phase_time`` is read from them.
         """
-        t_step = time.perf_counter()
-        self.transfers.complete_dispatched()
-        # deadline arithmetic runs on the step counter (a deterministic
-        # virtual clock), never the wall clock
-        self.sched.now = float(self.steps)
-        self._maybe_compact()
-        self._admit()
-        self.steps += 1
-        if not self.running:
-            self.transfers.drain()      # idle: nothing to overlap against
-            return
-        grown = self._grow_for_next_token()
-        if not self.running:
-            self.transfers.drain()
-            return
-        grown += self._cow_barrier()
-        self.sched.observe_growth(grown)
-        self.transfers.dispatch(lanes=(URGENT,))
-        self._maybe_prefetch()
-        self.transfers.dispatch(lanes=(BACKGROUND,))
-        t_sync = time.perf_counter()
-        self.phase_time["dispatch"] += t_sync - t_step
+        tel = self.telemetry
+        with tel.span("serve.step") as step_sp:
+            if step_sp.recording:
+                step_sp.set(step=self.steps,
+                            waiting=len(self.sched.policy)
+                            + len(self.sched.preempted))
+            with tel.span("serve.fence"):
+                self.transfers.complete_dispatched()
+            # deadline arithmetic runs on the step counter (a
+            # deterministic virtual clock), never the wall clock
+            self.sched.now = float(self.steps)
+            with tel.span("serve.compact"):
+                self._maybe_compact()
+            with tel.span("serve.admit"):
+                self._admit()
+            step_sp.set(batch=len(self.running))
+            self.steps += 1
+            if not self.running:
+                self.transfers.drain()  # idle: nothing to overlap against
+                return
+            with tel.span("serve.grow"):
+                grown = self._grow_for_next_token()
+                ran_dry = not self.running
+                if not ran_dry:
+                    grown += self._cow_barrier()
+            if ran_dry:
+                self.transfers.drain()
+                return
+            self.sched.observe_growth(grown)
+            with tel.span("serve.transfer"):
+                self.transfers.dispatch(lanes=(URGENT,))
+                self._maybe_prefetch()
+                self.transfers.dispatch(lanes=(BACKGROUND,))
+            self._decode_step(tel)
+
+    def _decode_step(self, tel) -> None:
+        """Sync the device tables or rows, decode every running slot,
+        retire what finished."""
         uploads = 0
-        if self.resident_tables:
-            rows, nbytes = self.strategy.sync_device_state_delta(
-                self.running)
-            if rows:
-                uploads += 1
-        else:
-            self._sync_device_state()
-            rows, nbytes = self.strategy.full_sync_cost()
-            uploads += 1
-        self.table_rows_updated += rows
-        self.table_sync_bytes += nbytes
-        t0 = time.perf_counter()
-        self.phase_time["sync"] += t0 - t_sync
-        if self.resident_tables:
-            if self._tok_dirty or self._tok_dev is None:
-                tok_dev = jnp.asarray(self._next_tok)
-                self._tok_dirty = False
-                uploads += 1
+        with tel.span("serve.sync", lead=True):
+            if self.resident_tables:
+                rows, nbytes = self.strategy.sync_device_state_delta(
+                    self.running)
+                if rows:
+                    uploads += 1
             else:
-                # steady state: this step's inputs ARE last step's
-                # argmax, still latched on device -- zero uploads
-                tok_dev = self._tok_dev
-            nxt_dev = self.strategy.decode_resident(self.params, tok_dev)
-            self._tok_dev = nxt_dev
-            nxt = np.asarray(nxt_dev)   # the (B,) host crossing
-            tokens = self._next_tok     # host truth of this step's inputs
-        else:
-            tokens = jnp.asarray(self._next_tok)
-            uploads += 1
-            logits = self.strategy.decode(self.params, tokens)
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))  # completion
-        t_retire = time.perf_counter()
-        self.phase_time["decode"] += t_retire - t0
-        self.host_uploads += uploads
-        self.sched.observe_decode(t_retire - t0)
-        # compute mark: any dispatched host copy that completes -- or
-        # speculative scatter that commits -- after this point genuinely
-        # overlapped a decode (honest per-engine `overlapped`)
-        self.transfers.note_compute()
-        self.decode_tokens += len(self.running)
-        for slot, req in list(self.running.items()):
-            req.generated.append(int(tokens[slot]))
-            self._next_tok[slot] = nxt[slot]
-            if len(req.generated) >= req.max_new or nxt[slot] == self.eos:
-                req.state = "done"
-                req.t_done = time.perf_counter()
-                self.done.append(req)
-                self.strategy.release(req.rid)
-                self._deregister_prefix(req)
-                del self.running[slot]
-        self.phase_time["retire"] += time.perf_counter() - t_retire
+                self._sync_device_state()
+                rows, nbytes = self.strategy.full_sync_cost()
+                uploads += 1
+            self.table_rows_updated += rows
+            self.table_sync_bytes += nbytes
+        with tel.span("serve.decode") as dec_sp:
+            if dec_sp.recording:
+                # the fused decode's update width
+                dec_sp.set(W=self.strategy._bucket(rows, self.slots))
+            if self.resident_tables:
+                if self._tok_dirty or self._tok_dev is None:
+                    tok_dev = jnp.asarray(self._next_tok)
+                    self._tok_dirty = False
+                    uploads += 1
+                else:
+                    # steady state: this step's inputs ARE last step's
+                    # argmax, still latched on device -- zero uploads
+                    tok_dev = self._tok_dev
+                nxt_dev = self.strategy.decode_resident(self.params, tok_dev)
+                self._tok_dev = nxt_dev
+                nxt = np.asarray(nxt_dev)   # the (B,) host crossing
+                tokens = self._next_tok     # host truth of this step's inputs
+            else:
+                tokens = jnp.asarray(self._next_tok)
+                uploads += 1
+                logits = self.strategy.decode(self.params, tokens)
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))  # completion
+        with tel.span("serve.retire"):
+            self.host_uploads += uploads
+            self.sched.observe_decode(dec_sp.seconds)
+            # compute mark: any dispatched host copy that completes -- or
+            # speculative scatter that commits -- after this point
+            # genuinely overlapped a decode (honest per-engine
+            # `overlapped`)
+            self.transfers.note_compute()
+            self.decode_tokens += len(self.running)
+            for slot, req in list(self.running.items()):
+                req.generated.append(int(tokens[slot]))
+                self._next_tok[slot] = nxt[slot]
+                if (len(req.generated) >= req.max_new
+                        or nxt[slot] == self.eos):
+                    req.state = "done"
+                    req.t_done = time.perf_counter()
+                    self.done.append(req)
+                    self.strategy.release(req.rid)
+                    self._deregister_prefix(req)
+                    del self.running[slot]
 
     def serve(self, source=None, max_steps: int = 10_000) -> List[Request]:
         """Arrival-driven serving loop: the continuous-batching request
@@ -766,6 +809,19 @@ class Engine:
 
     # ---------------- introspection ----------------
     @property
+    def phase_time(self) -> Dict[str, float]:
+        """Seconds of the step loop's four phases, from the span totals.
+        ``dispatch`` runs from a step's start to its sync (fence,
+        compaction, admission with its prefill, growth, transfers), so a
+        step that ends before a sync (nothing running) counts in no
+        phase."""
+        tel = self.telemetry
+        sync = tel.totals("serve.sync")
+        return {"dispatch": sync.lead_s, "sync": sync.seconds,
+                "decode": tel.totals("serve.decode").seconds,
+                "retire": tel.totals("serve.retire").seconds}
+
+    @property
     def stats(self) -> Dict[str, float]:
         st = self.store.stats
         return {
@@ -796,7 +852,7 @@ class Engine:
                                       / max(self.steps, 1)),
             "table_sync_bytes": self.table_sync_bytes,
             "table_rows_updated": self.table_rows_updated,
-            "phase_time_s": dict(self.phase_time),
+            "phase_time_s": self.phase_time,
             "compactions": self.arena.compactions,
             "blocks_compacted": self.arena.blocks_compacted,
             "watermark_effective": self.sched.watermark,

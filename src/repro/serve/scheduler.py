@@ -117,6 +117,7 @@ class Request:
     # wall-clock latency telemetry (perf_counter seconds; stamped by the
     # engine, never read by policy -- policy clocks are virtual)
     t_submit: float = -1.0
+    t_admit: float = -1.0              # start of the first prefill taking it
     t_first: float = -1.0              # first token available (prefill done)
     t_done: float = -1.0
 
