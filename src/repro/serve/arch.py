@@ -851,6 +851,7 @@ class ConstantStateStrategy(CacheStrategy):
         self._upd = None
         self._rows_dev = None           # device-resident row indices
         self._fused = None              # cached fused decode jit
+        self._prefill = None            # cached prefill jit
 
     @property
     def sink(self) -> int:
@@ -995,27 +996,40 @@ class ConstantStateStrategy(CacheStrategy):
             jnp.asarray(upd_slots), jnp.asarray(upd_rows))
         return nxt
 
+    def _prefill_fn(self):
+        """One jitted, pool-donated program per (rows, width): zero state
+        -> padded prefill -> argmax of each row's last logits -> state
+        scatter into the pool rows, in place."""
+        if self._prefill is None:
+            model = self.model
+
+            def constant_state_prefill(p, toks, lengths, pool, idx):
+                state0 = model.init_state(toks.shape[0])
+                last, state = model.prefill(p, {"tokens": toks}, state0,
+                                            lengths)
+                pool = pool.at[idx, 0].set(model.state_to_rows(state))
+                return jnp.argmax(last, axis=-1), pool
+
+            self._prefill = jax.jit(constant_state_prefill,
+                                    donate_argnums=(3,))
+        return self._prefill
+
     def prefill(self, params, batch):
         """Padded batched prefill from zero state; ``lengths`` masks the
         right padding out of the SSM scan exactly, so this is
         token-identical to per-sequence prefill."""
-        lens = [req.tokens_held for _, req, _ in batch]
-        S = self.prefill_width(max(lens))
-        toks = np.zeros((len(batch), S), np.int64)
+        lens = np.asarray([req.tokens_held for _, req, _ in batch], np.int32)
+        toks = np.zeros((len(batch), self.prefill_width(int(lens.max()))),
+                        np.int32)
         for row, (_, req, _) in enumerate(batch):
             toks[row, : lens[row]] = np.concatenate(
-                [np.asarray(req.prompt, np.int64),
-                 np.asarray(req.generated, np.int64)])
-        state0 = self.model.init_state(len(batch))
-        last, state = self.model.prefill(
-            params, {"tokens": jnp.asarray(toks)}, state0,
-            jnp.asarray(lens, jnp.int32))
-        nxt = np.asarray(jnp.argmax(last, axis=-1))   # forces completion
-        idx = jnp.asarray([self.mgr.row(req.rid) for _, req, _ in batch],
-                          jnp.int32)
-        self.mgr.pool = self.mgr.pool.at[idx, 0].set(
-            self.model.state_to_rows(state))
-        return nxt, sum(lens)
+                [np.asarray(req.prompt, np.int32),
+                 np.asarray(req.generated, np.int32)])
+        idx = np.asarray([self.mgr.row(req.rid) for _, req, _ in batch],
+                         np.int32)
+        nxt, self.mgr.pool = self._prefill_fn()(params, toks, lens,
+                                                self.mgr.pool, idx)
+        return np.asarray(nxt), int(lens.sum())
 
     # -- restart / teardown / audit --
     def adopt_restored(self, rid) -> None:

@@ -19,6 +19,7 @@ on any mapping mutation that forgot to mark its slot dirty.
 import numpy as np
 import pytest
 import jax
+import jax.numpy as jnp
 
 from repro.configs.base import get_config
 from repro.models.api import build_model
@@ -28,10 +29,11 @@ from conftest import assert_engine_quiescent
 
 @pytest.fixture(scope="module")
 def families():
-    """One tiny model per discipline: paged / constant / composite."""
+    """One tiny model per discipline: paged / constant / composite, and
+    rwkv6 beside mamba2 on the constant-state discipline."""
     out = {}
     for key, name in (("dense", "gemma_2b"), ("ssm", "mamba2_370m"),
-                      ("hybrid", "zamba2_2p7b")):
+                      ("hybrid", "zamba2_2p7b"), ("rwkv6", "rwkv6_7b")):
         cfg = get_config(name).reduced()
         model = build_model(cfg)
         params, _ = model.init(jax.random.PRNGKey(hash(key) % 2**31))
@@ -265,6 +267,50 @@ def test_resident_steady_state_stops_uploading(families, rng):
     eng2.run(100)
     assert eng2.stats["host_uploads"] == 2 * eng2.steps
     assert list(eng2.done[0].generated) == list(eng.done[0].generated)
+
+
+@pytest.mark.parametrize("family", ["ssm", "rwkv6"])
+def test_constant_state_batched_prefill_matches_per_sequence(families,
+                                                             family):
+    """The jitted, pool-donated prefill of rows whose lengths straddle two
+    padded widths gives each row the next token and state row of its own
+    per-sequence prefill, and leaves another live sequence's row and the
+    sink row bit-identical."""
+    cfg, model, params = families[family]
+    eng = _engine(model, params, True, slots=4, num_blocks=8)
+    strat = eng.strategy
+    q = strat.prefill_quantum
+    rng = np.random.RandomState(5)
+    lens = (q - 3, q + 4, 2 * q - 1)          # widths q, 2q, 2q
+    reqs = [Request(rid=i, prompt=rng.randint(2, cfg.vocab_size, size=n),
+                    max_new=1) for i, n in enumerate(lens)]
+    live = Request(rid=9, prompt=rng.randint(2, cfg.vocab_size, size=q),
+                   max_new=1)
+    for req in (live, *reqs):
+        strat.admit(req.rid, req.tokens_held, req.tenant)
+    strat.prefill(params, [(0, live, 0)])     # a live sequence's state
+
+    def rows(ids):
+        return np.asarray(strat.mgr.pool[jnp.asarray(ids, jnp.int32), 0])
+
+    kept = [strat.mgr.row(live.rid), strat.sink]
+    before = rows(kept)
+    assert before[0].any()
+    nxt, billed = strat.prefill(
+        params, [(i, req, 0) for i, req in enumerate(reqs)])
+    assert billed == sum(lens) and isinstance(nxt, np.ndarray)
+    np.testing.assert_array_equal(rows(kept), before)
+    got = rows([strat.mgr.row(req.rid) for req in reqs])
+    for i, req in enumerate(reqs):
+        toks = np.zeros((1, strat.prefill_width(lens[i])), np.int32)
+        toks[0, :lens[i]] = req.prompt
+        last, state = model.prefill(
+            params, {"tokens": jnp.asarray(toks)}, model.init_state(1),
+            jnp.asarray([lens[i]], jnp.int32))
+        assert nxt[i] == int(jnp.argmax(last[0]))
+        np.testing.assert_allclose(
+            got[i], np.asarray(model.state_to_rows(state))[0],
+            rtol=1e-5, atol=1e-5)
 
 
 def test_report_renders_decode_path_section():

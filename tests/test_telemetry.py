@@ -30,7 +30,8 @@ def ticks(monkeypatch):
 @pytest.fixture(scope="module")
 def families():
     out = {}
-    for key, name in (("paged", "gemma_2b"), ("ssm", "mamba2_370m")):
+    for key, name in (("paged", "gemma_2b"), ("ssm", "mamba2_370m"),
+                      ("rwkv6", "rwkv6_7b")):
         cfg = get_config(name).reduced()
         model = build_model(cfg)
         params, _ = model.init(jax.random.PRNGKey(7))
@@ -130,6 +131,26 @@ def test_engine_prefill_compiles_land_in_prefill_span(families):
     prefill = eng.telemetry.totals("serve.prefill")
     assert prefill.compiles >= 1 and prefill.count == 1
     assert eng.telemetry.totals("serve.step").count == eng.steps
+    assert_engine_quiescent(eng)
+
+
+@pytest.mark.parametrize("family", ["ssm", "rwkv6"])
+def test_constant_state_prefill_compiles_once_per_shape(family, families):
+    """The constant-state prefill is one jitted program per (rows,
+    width): the first prefill at a shape compiles it, a second at that
+    shape charges no compile to ``serve.prefill``."""
+    cfg, model, params = families[family]
+    eng = _engine(model, params, slots=2, num_blocks=4)
+    rng = np.random.RandomState(0)
+    q = eng.strategy.prefill_quantum
+    eng.submit(_request(cfg, 0, rng, n=q + 3, max_new=2))
+    eng.run(max_steps=50)
+    first = eng.telemetry.totals("serve.prefill").compiles
+    assert first >= 1
+    eng.submit(_request(cfg, 1, rng, n=2 * q, max_new=2))  # same width
+    eng.run(max_steps=50)
+    prefill = eng.telemetry.totals("serve.prefill")
+    assert prefill.count == 2 and prefill.compiles == first
     assert_engine_quiescent(eng)
 
 
