@@ -99,6 +99,12 @@ class ModelConfig:
     tie_embeddings: bool = True
     post_norms: bool = False        # gemma2/3: post-attn/post-mlp norms
     embed_scale: bool = False       # gemma family: x *= sqrt(d_model)
+    # MiniCPM's muP multipliers: embeddings x scale_emb, each residual
+    # branch x scale_depth / sqrt(num_layers), the head's input
+    # x dim_model_base / d_model.  Unset: none applied.
+    scale_emb: float = 1.0
+    scale_depth: Optional[float] = None
+    dim_model_base: Optional[int] = None
     dtype: str = "bfloat16"
     # serving/paging knobs (the paper's block quantum)
     kv_block_tokens: int = 64

@@ -1,5 +1,9 @@
 """MiniCPM3-4B: 62L, d=2560, 40H MLA (q_lora=768, kv_lora=256), d_ff=6400,
-vocab 73448.  [hf:openbmb/MiniCPM3-4B]"""
+vocab 73448.  [hf:openbmb/MiniCPM3-4B]
+
+The head is tied to the embedding and no muP multiplier is applied; the
+published model, with its own head and the multipliers, is
+``minicpm3_4b_published``."""
 
 from repro.configs.base import MLAConfig, ModelConfig
 

@@ -60,7 +60,34 @@ class PagedKVConfig:
     def token_shape(self) -> Tuple[int, ...]:
         return (self.head_dim,) if self.latent else (self.kv_heads, self.head_dim)
 
+    @property
+    def packed(self) -> bool:
+        """A single latent stream is stored ``tokens_per_row`` tokens to
+        a pool row: ``(L, NB, BT // K, K * W)``.  MLA's latent (kv_lora +
+        rope, 288 for MiniCPM3) is no multiple of the TPU's 128 lanes, so
+        the chip lays a ``(L, NB, BT, W)`` pool out block-minor, and every
+        in-place write and block copy would first relay the whole pool
+        out (a second, padded copy of it).  Rows of ``K * W`` lanes keep
+        the natural layout, and a block stays one contiguous tile run."""
+        return self.latent and not self.latent_rope
+
+    @property
+    def tokens_per_row(self) -> int:
+        """K: the fewest tokens (a divisor of ``block_tokens``) whose
+        latents fill a whole number of 128-lane rows; the whole block
+        where none does.  1 for every pool that is not ``packed``."""
+        if not self.packed:
+            return 1
+        for k in range(1, self.block_tokens + 1):
+            if self.block_tokens % k == 0 and k * self.head_dim % 128 == 0:
+                return k
+        return self.block_tokens
+
     def pool_shape(self) -> Tuple[int, ...]:
+        if self.packed:
+            k = self.tokens_per_row
+            return (self.num_layers, self.num_blocks, self.block_tokens // k,
+                    k * self.head_dim)
         return (self.num_layers, self.num_blocks, self.block_tokens,
                 *self.token_shape())
 
@@ -84,12 +111,44 @@ class PagedKVConfig:
                 * jnp.dtype(self.dtype).itemsize)
 
 
+def pool_wide_ids(ids: jax.Array, dp_groups: int,
+                  num_blocks: int) -> jax.Array:
+    """Group-local block ids (B, ...) as ids into the whole pool: row b
+    belongs to data-parallel group ``b // (B // dp_groups)``."""
+    if dp_groups <= 1:
+        return ids
+    B = ids.shape[0]
+    first = (jnp.arange(B) // (B // dp_groups)) * (num_blocks // dp_groups)
+    return ids + first.reshape((B,) + (1,) * (ids.ndim - 1))
+
+
+def write_packed_tokens(pool: jax.Array, kv_new: jax.Array,
+                        phys: jax.Array, off: jax.Array) -> jax.Array:
+    """Write one token per sequence into every layer of a packed pool
+    (``PagedKVConfig.packed``): ``kv_new`` (L, B, W) lands at block
+    ``phys[b]``, offset ``off[b]``.  One update per layer and sequence,
+    each one token wide, keeps the donated pool in place; wider updates
+    (all layers at once, a scatter) make XLA relay the pool out first."""
+    L, B, W = kv_new.shape
+    k = pool.shape[-1] // W
+    val = kv_new.astype(pool.dtype)
+
+    def put(b, pool):
+        row, col = off[b] // k, off[b] % k * W
+        for layer in range(L):
+            tok = jax.lax.dynamic_slice(val, (layer, b, 0), (1, 1, W))
+            pool = jax.lax.dynamic_update_slice(
+                pool, tok[:, :, None], (layer, phys[b], row, col))
+        return pool
+    return jax.lax.fori_loop(0, B, put, pool)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class PagedKVCache:
     """Functional paged KV state threaded through decode steps."""
 
-    k_pool: jax.Array            # (L, NB, BT, KVH, HD) or (L, NB, BT, LAT) for MLA
+    k_pool: jax.Array            # (L, NB, BT, KVH, HD); MLA (L, NB, BT/K, K*LAT)
     v_pool: Optional[jax.Array]  # None in latent (MLA) mode
     block_tables: jax.Array      # (B, max_blocks_per_seq) int32
     seq_lens: jax.Array          # (B,) int32 -- tokens already cached
@@ -151,6 +210,11 @@ class PagedKVCache:
         with seq_lens advanced by 1.
         """
         phys, off = self._addr(self.seq_lens)
+        phys = pool_wide_ids(phys, self.config.dp_groups, self.k_pool.shape[1])
+        if self.config.packed:
+            return dataclasses.replace(
+                self, k_pool=write_packed_tokens(self.k_pool, k_new, phys, off),
+                seq_lens=self.seq_lens + 1)
         k_pool = self.k_pool.at[:, phys, off].set(
             k_new.astype(self.config.dtype))
         v_pool = self.v_pool
@@ -172,6 +236,18 @@ class PagedKVCache:
         """pool (L, NB, BT, ...) .at[:, tbl].set(payload) with dp-group
         local block ids when dp_groups > 1 (see PagedKVConfig)."""
         dp = self.config.dp_groups
+        if self.config.packed:
+            B, nblk = tbl.shape
+            tbl = pool_wide_ids(tbl, dp, pool.shape[1])
+
+            def put(i, pool):   # one whole block, all layers, in place
+                b, j = i // nblk, i % nblk
+                blk = jax.lax.dynamic_slice_in_dim(
+                    jax.lax.dynamic_index_in_dim(payload, b, 1, False),
+                    j, 1, axis=1)
+                return jax.lax.dynamic_update_slice(pool, blk,
+                                                    (0, tbl[b, j], 0, 0))
+            return jax.lax.fori_loop(0, B * nblk, put, pool)
         if dp <= 1:
             return pool.at[:, tbl].set(payload)
         L, NB = pool.shape[:2]
@@ -196,7 +272,8 @@ class PagedKVCache:
         assert S % bt == 0, "prefill length must be block-aligned"
         nblk = S // bt
         tbl = jnp.maximum(self.block_tables[:, :nblk], 0)       # (B, nblk)
-        kb = k.reshape(L, B, nblk, bt, *k.shape[3:]).astype(self.config.dtype)
+        kb = k.reshape(L, B, nblk, *self.config.pool_shape()[2:]
+                       ).astype(self.config.dtype)
         k_pool = self._scatter_blocks(self.k_pool, tbl, kb)
         v_pool = self.v_pool
         if v is not None:
@@ -218,7 +295,7 @@ class PagedKVCache:
         """
         tbl = jnp.maximum(self.block_tables, 0)  # clip NULL
         k = layer_k[tbl]            # (B, nblk, BT, ...)
-        k = k.reshape(k.shape[0], -1, *k.shape[3:])
+        k = k.reshape(k.shape[0], -1, *self.config.token_shape())
         if layer_v is None:
             return k, None
         v = layer_v[tbl]

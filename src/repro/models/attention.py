@@ -288,21 +288,22 @@ def _paged_ref(q, k_pool, v_pool, block_tables, seq_lens, *,
     Fully-masked rows (seq_len == 0) return l == 0, m == -1e30, o == 0.
     """
     B, KVH, G, Dk = q.shape
-    NB, BT = k_pool.shape[:2]
-    MB = block_tables.shape[1]
     Dv = v_dim if v_dim is not None else v_pool.shape[-1]
 
     tbl = jnp.maximum(block_tables, 0)
-    k = _grouped_gather(k_pool, tbl, dp_groups).reshape(B, MB * BT, KVH, -1)
+    # (B, MB, <block>) -> (B, MB * BT, KVH, Dk); a packed latent block
+    # (BT / K rows of K tokens) flattens to the same token order
+    k = _grouped_gather(k_pool, tbl, dp_groups).reshape(B, -1, KVH, Dk)
+    S = k.shape[1]
     v = _grouped_gather(v_pool, tbl, dp_groups
-                        ).reshape(B, MB * BT, KVH, -1)[..., :Dv]
+                        ).reshape(B, S, KVH, -1)[..., :Dv]
     # bf16 operands + f32 accumulation (MXU-style): avoids materializing
     # f32 copies of the gathered KV views, the largest decode tensors
     s = jnp.einsum("bhgd,bshd->bhgs", (q * scale).astype(k.dtype), k,
                    preferred_element_type=jnp.float32)
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
-    pos = jnp.arange(MB * BT)[None, :]
+    pos = jnp.arange(S)[None, :]
     valid = pos < seq_lens[:, None]
     if window is not None:
         lo = jnp.where(window > 0, seq_lens[:, None] - window + 1,
@@ -421,7 +422,8 @@ def mla_decode(p: Params, x: jax.Array, cfg: ModelConfig,
                seq_lens: jax.Array, dp_groups: int = 1, **_):
     """Absorbed-MLA decode over the latent paged pool.
 
-    c_pool: (NB, BT, 1, latent_dim) where latent = kv_lora || k_rope.
+    c_pool: (NB, BT / K, K * latent_dim), K tokens to a row (see
+    ``PagedKVConfig.packed``), where latent = kv_lora || k_rope.
     Scores: q_nope^T W_kb^T c + q_rope^T k_rope  ==  q_eff . latent
     with q_eff = [W_kb^T q_nope, q_rope].  Output: (attn @ c) absorbed
     through W_vb then W_o.  Cache traffic per token: latent_dim values

@@ -186,6 +186,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     GQA handled by reshaping q to (B, Sq, KVH, G, Dk).  ``q_offset`` is
     the absolute position of q[0] (prefill continuation).
     Memory: O(B * H * q_chunk * Sk) instead of O(B * H * Sq * Sk).
+    A length that is not a whole number of chunks is padded with query
+    rows past the end, whose outputs are dropped: every real row's mask
+    depends only on its own position, so the padding changes none.
     """
     B, Sq, H, Dk = q.shape
     _, Sk, KVH, _ = k.shape
@@ -193,9 +196,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if scale is None:
         scale = Dk ** -0.5
     qc = min(q_chunk, Sq)
-    assert Sq % qc == 0, (Sq, qc)
+    nc = -(-Sq // qc)
+    if nc * qc != Sq:
+        q = jnp.pad(q, ((0, 0), (0, nc * qc - Sq), (0, 0), (0, 0)))
 
-    qr = (q.reshape(B, Sq // qc, qc, KVH, G, Dk)
+    qr = (q.reshape(B, nc, qc, KVH, G, Dk)
           .transpose(1, 0, 3, 4, 2, 5))              # (nc, B, KVH, G, qc, Dk)
     kT = k.transpose(0, 2, 3, 1)                     # (B, KVH, Dk, Sk)
     vT = v.transpose(0, 2, 1, 3)                     # (B, KVH, Sk, Dv)
@@ -227,7 +232,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         return o                                     # (B, KVH, G, qc, Dv)
 
     out = jax.lax.map(lambda args: chunk_fn(*args),
-                      (jnp.arange(Sq // qc), qr))    # (nc, B, KVH, G, qc, Dv)
+                      (jnp.arange(nc), qr))          # (nc, B, KVH, G, qc, Dv)
     Dv = v.shape[-1]
-    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq, H, Dv)
-    return out.astype(q.dtype)
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, nc * qc, H, Dv)
+    return out[:, :Sq].astype(q.dtype)

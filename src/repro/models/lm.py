@@ -7,8 +7,10 @@ vs dense) rides along as scanned metadata arrays; MoE models with leading
 dense layers run those outside the main scan.
 
 Decode threads the PagedKVCache's per-layer pool slices through the scan
-(xs in, updated ys out) -- one block-table lookup schedule shared by all
-layers, which is the paper's single-arena/many-tenants design.
+(xs in; updated slices out as ys, or for a packed latent pool only the
+new latents, written in place after the scan) -- one block-table lookup
+schedule shared by all layers, which is the paper's
+single-arena/many-tenants design.
 """
 
 from __future__ import annotations
@@ -21,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core.paged_kv import PagedKVCache, PagedKVConfig
+from repro.core.paged_kv import (PagedKVCache, PagedKVConfig,
+                                  pool_wide_ids, write_packed_tokens)
 from repro.launch.shardings import constrain
 from repro.models import attention as A
 from repro.models import moe as MOE
@@ -159,21 +162,45 @@ class DecoderLM:
         return windows, thetas
 
     # ---------------- embedding / head ----------------
-    def _embed(self, p, batch):
+    def _embed_tokens(self, p, tokens):
         cfg = self.cfg
-        x = p["embed"][batch["tokens"]]
+        x = p["embed"][tokens]
         if cfg.embed_scale:
             x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        if cfg.scale_emb != 1.0:
+            x = x * jnp.asarray(cfg.scale_emb, x.dtype)
+        return x
+
+    def _embed(self, p, batch):
+        cfg = self.cfg
+        x = self._embed_tokens(p, batch["tokens"])
         if cfg.num_image_tokens:
             img = batch["image_embeds"].astype(x.dtype) @ p["img_proj"]
             x = jnp.concatenate([img, x], axis=1)
         return x
 
+    def _final_norm(self, p, x):
+        """The head's input: the final norm, then MiniCPM's width
+        multiplier ``dim_model_base / d_model`` where one is set."""
+        cfg = self.cfg
+        x = rmsnorm(x, p["final_norm"], cfg.norm_eps, gemma_style=True)
+        if cfg.dim_model_base:
+            x = x * jnp.asarray(cfg.dim_model_base / cfg.d_model, x.dtype)
+        return x
+
+    def _residual(self, x, y):
+        """x + y, with MiniCPM's depth multiplier ``scale_depth /
+        sqrt(num_layers)`` on the branch where one is set."""
+        cfg = self.cfg
+        if cfg.scale_depth:
+            y = y * jnp.asarray(cfg.scale_depth / cfg.num_layers ** 0.5,
+                                y.dtype)
+        return x + y
+
     def _head(self, p, x):
         cfg = self.cfg
         w = p["embed"].T if cfg.tie_embeddings else p["lm_head"]
-        logits = rmsnorm(x, p["final_norm"], cfg.norm_eps,
-                         gemma_style=True) @ w
+        logits = self._final_norm(p, x) @ w
         logits = logits.astype(jnp.float32)
         if cfg.final_softcap is not None:
             logits = cfg.final_softcap * jnp.tanh(logits / cfg.final_softcap)
@@ -194,7 +221,7 @@ class DecoderLM:
                                  rope_theta=theta)
         if cfg.post_norms:
             y = rmsnorm(y, lp["ln1_post"], cfg.norm_eps, gemma_style=True)
-        x = x + y
+        x = self._residual(x, y)
         x = constrain(x, "batch", "seq", None)
         h = rmsnorm(x, lp["ln2"], cfg.norm_eps, gemma_style=True)
         aux = jnp.zeros((), jnp.float32)
@@ -204,7 +231,7 @@ class DecoderLM:
             y = mlp(h, lp["ff"], cfg.mlp)
         if cfg.post_norms:
             y = rmsnorm(y, lp["ln2_post"], cfg.norm_eps, gemma_style=True)
-        x = x + y
+        x = self._residual(x, y)
         x = constrain(x, "batch", "seq", None)
         return x, aux, (kv if collect_kv else None)
 
@@ -259,7 +286,7 @@ class DecoderLM:
                                         q_chunk=q_chunk)
         if cfg.num_image_tokens:            # loss only on text positions
             x = x[:, cfg.num_image_tokens:]
-        xn = rmsnorm(x, p["final_norm"], cfg.norm_eps, gemma_style=True)
+        xn = self._final_norm(p, x)
         w = p["embed"].T if cfg.tie_embeddings else p["lm_head"]
         nll, cnt = chunked_lm_loss(xn, w, batch["targets"],
                                    final_softcap=cfg.final_softcap)
@@ -338,40 +365,31 @@ class DecoderLM:
                           cache: PagedKVCache):
         cfg = self.cfg
         bt = cache.config.block_tokens
-        x = p["embed"][tokens]
-        if cfg.embed_scale:
-            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        x = self._embed_tokens(p, tokens)
         x = constrain(x, "batch", None)
         tables, lens = cache.block_tables, cache.seq_lens
         dp = cache.config.dp_groups
 
         def layer_decode(lp, x, k_pool_l, v_pool_l, window, theta):
+            """One layer over the pool as it stands; every attention
+            variant merges the new token's own term itself, so the
+            layer returns that token's entries instead of writing them."""
             h = rmsnorm(x, lp["ln1"], cfg.norm_eps, gemma_style=True)
             if cfg.attention == "mla" and cache.config.latent_rope:
-                y, (c_new, r_new) = A.mla_decode_split(
+                y, new = A.mla_decode_split(
                     lp["attn"], h, cfg, k_pool_l, v_pool_l, tables, lens,
                     dp_groups=dp)
-                k_pool_l = self._write_token(k_pool_l, c_new, tables, lens,
-                                             bt, dp)
-                v_pool_l = self._write_token(v_pool_l, r_new, tables, lens,
-                                             bt, dp)
             elif cfg.attention == "mla":
                 y, latent_new = A.mla_decode(lp["attn"], h, cfg, k_pool_l,
                                              tables, lens, dp_groups=dp)
-                k_pool_l = self._write_token(k_pool_l, latent_new,
-                                             tables, lens, bt, dp)
-                v_pool_l = None
+                new = (latent_new,)
             else:
-                y, (k_new, v_new) = A.gqa_decode(
+                y, new = A.gqa_decode(
                     lp["attn"], h, cfg, k_pool_l, v_pool_l, tables, lens,
                     window=window, rope_theta=theta, dp_groups=dp)
-                k_pool_l = self._write_token(k_pool_l, k_new, tables, lens,
-                                             bt, dp)
-                v_pool_l = self._write_token(v_pool_l, v_new, tables, lens,
-                                             bt, dp)
             if cfg.post_norms:
                 y = rmsnorm(y, lp["ln1_post"], cfg.norm_eps, gemma_style=True)
-            x = x + y
+            x = self._residual(x, y)
             h = rmsnorm(x, lp["ln2"], cfg.norm_eps, gemma_style=True)
             if self.is_moe and "router" in lp["ff"]:
                 y, _ = moe_ffn_dispatch(lp["ff"], h[:, None], cfg)
@@ -380,15 +398,30 @@ class DecoderLM:
                 y = mlp(h, lp["ff"], cfg.mlp)
             if cfg.post_norms:
                 y = rmsnorm(y, lp["ln2_post"], cfg.norm_eps, gemma_style=True)
-            x = x + y
-            return constrain(x, "batch", None), k_pool_l, v_pool_l
+            x = self._residual(x, y)
+            return constrain(x, "batch", None), tuple(new)
+
+        packed = cache.config.packed
+
+        def write(pool_l, new, vpool_l):
+            """A per-layer pool slice with this step's token written in,
+            for pools that are not packed."""
+            kp = self._write_token(pool_l, new[0], tables, lens, bt, dp)
+            vp = (None if vpool_l is None else
+                  self._write_token(vpool_l, new[1], tables, lens, bt, dp))
+            return kp, vp
 
         # leading dense layers (deepseek): unscanned
+        dense_new = []
         for i in range(self.n_dense):
             lp = jax.tree.map(lambda t: t[i], p["dense_layers"])
             kp = cache.k_pool[i]
             vp = cache.v_pool[i] if cache.v_pool is not None else None
-            x, kp, vp = layer_decode(lp, x, kp, vp, None, None)
+            x, new = layer_decode(lp, x, kp, vp, None, None)
+            if packed:
+                dense_new.append(new[0])
+                continue
+            kp, vp = write(kp, new, vp)
             cache = dataclasses.replace(
                 cache, k_pool=cache.k_pool.at[i].set(kp),
                 v_pool=(cache.v_pool.at[i].set(vp)
@@ -396,19 +429,25 @@ class DecoderLM:
 
         windows, thetas = self._layer_meta("scan")
 
-        # pools thread through the scan as xs -> ys (each layer's slice
-        # written once to the stacked output).  A carry-with-DUS variant
-        # was tried and REFUTED: XLA copies the whole carry per
-        # iteration under the read-modify-write (EXPERIMENTS.md §Perf).
+        # Pools thread through the scan as xs.  A packed latent pool is
+        # only read there: each layer emits its new token's latent as ys
+        # (L, B, W), written into the donated pool once after the scan,
+        # in place.  Other pools emit their updated slices as ys (each
+        # layer's slice written once to the stacked output).  A
+        # carry-with-DUS variant was tried and REFUTED: XLA copies the
+        # whole carry per iteration under the read-modify-write
+        # (EXPERIMENTS.md §Perf).
         def body(x, xs):
             if cache.v_pool is None:
                 lp, kp, window, theta = xs
                 vp = None
             else:
                 lp, kp, vp, window, theta = xs
-            x, kp, vp = layer_decode(lp, x, kp, vp, window, theta)
-            ys = (kp,) if vp is None else (kp, vp)
-            return x, ys
+            x, new = layer_decode(lp, x, kp, vp, window, theta)
+            if packed:
+                return x, new[0]
+            kp, vp = write(kp, new, vp)
+            return x, ((kp,) if vp is None else (kp, vp))
 
         k_scan = cache.k_pool[self.n_dense:]
         if cache.v_pool is None:
@@ -416,14 +455,24 @@ class DecoderLM:
         else:
             xs = (p["layers"], k_scan, cache.v_pool[self.n_dense:],
                   windows, thetas)
-        x, pools = jax.lax.scan(body, x, xs)
-        k_new = (cache.k_pool.at[self.n_dense:].set(pools[0])
-                 if self.n_dense else pools[0])
-        if cache.v_pool is None:
+        x, ys = jax.lax.scan(body, x, xs)
+        if packed:
+            latents = (jnp.concatenate([jnp.stack(dense_new), ys])
+                       if dense_new else ys)
+            phys = pool_wide_ids(tables[jnp.arange(tables.shape[0]),
+                                        lens // bt], dp,
+                                 cache.k_pool.shape[1])
+            k_new = write_packed_tokens(cache.k_pool, latents, phys,
+                                        lens % bt)
             v_new = None
         else:
-            v_new = (cache.v_pool.at[self.n_dense:].set(pools[1])
-                     if self.n_dense else pools[1])
+            k_new = (cache.k_pool.at[self.n_dense:].set(ys[0])
+                     if self.n_dense else ys[0])
+            if cache.v_pool is None:
+                v_new = None
+            else:
+                v_new = (cache.v_pool.at[self.n_dense:].set(ys[1])
+                         if self.n_dense else ys[1])
         cache = dataclasses.replace(cache, k_pool=k_new, v_pool=v_new,
                                     seq_lens=cache.seq_lens + 1)
         logits = self._head(p, x[:, None] if x.ndim == 2 else x)
@@ -515,9 +564,7 @@ class DecoderLM:
         cfg = self.cfg
         assert cfg.attention != "mla", "suffix prefill is GQA/MQA-only"
         bt = cache.config.block_tokens
-        x = p["embed"][tokens]
-        if cfg.embed_scale:
-            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        x = self._embed_tokens(p, tokens)
         x = constrain(x, "batch", None, None)
         tables = cache.block_tables
         dp = cache.config.dp_groups
@@ -530,7 +577,7 @@ class DecoderLM:
                 dp_groups=dp)
             if cfg.post_norms:
                 y = rmsnorm(y, lp["ln1_post"], cfg.norm_eps, gemma_style=True)
-            x = x + y
+            x = self._residual(x, y)
             h = rmsnorm(x, lp["ln2"], cfg.norm_eps, gemma_style=True)
             if self.is_moe and "router" in lp["ff"]:
                 y, _ = moe_ffn_dispatch(lp["ff"], h, cfg)
@@ -538,7 +585,7 @@ class DecoderLM:
                 y = mlp(h, lp["ff"], cfg.mlp)
             if cfg.post_norms:
                 y = rmsnorm(y, lp["ln2_post"], cfg.norm_eps, gemma_style=True)
-            x = x + y
+            x = self._residual(x, y)
             return constrain(x, "batch", None, None), k_pool_l, v_pool_l
 
         # leading dense layers (deepseek): unscanned

@@ -406,6 +406,17 @@ class CacheStrategy:
         q = self.prefill_quantum
         return -(-n // q) * q
 
+    @property
+    def table_tokens(self) -> Optional[int]:
+        """Tokens one decode gathers through the block tables: every
+        slot's whole table, live or not.  None for a discipline with no
+        paged cache."""
+        cache = getattr(self, "cache", None)
+        if cache is None:
+            return None
+        return (self.slots * cache.config.max_blocks_per_seq
+                * cache.config.block_tokens)
+
     def full_sync_cost(self) -> Tuple[int, int]:
         """(rows, bytes) one full-rebuild sync uploads -- the eager
         fallback's per-step cost, reported beside the delta path's."""

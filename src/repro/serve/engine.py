@@ -692,8 +692,9 @@ class Engine:
             if not self.running:
                 self.transfers.drain()  # idle: nothing to overlap against
                 return
-            with tel.span("serve.grow"):
+            with tel.span("serve.grow") as grow_sp:
                 grown = self._grow_for_next_token()
+                grow_sp.set(blocks=grown)
                 ran_dry = not self.running
                 if not ran_dry:
                     grown += self._cow_barrier()
@@ -711,7 +712,7 @@ class Engine:
         """Sync the device tables or rows, decode every running slot,
         retire what finished."""
         uploads = 0
-        with tel.span("serve.sync", lead=True):
+        with tel.span("serve.sync", lead=True) as sync_sp:
             if self.resident_tables:
                 rows, nbytes = self.strategy.sync_device_state_delta(
                     self.running)
@@ -723,10 +724,19 @@ class Engine:
                 uploads += 1
             self.table_rows_updated += rows
             self.table_sync_bytes += nbytes
+            sync_sp.set(rows=rows, bytes=nbytes)
         with tel.span("serve.decode") as dec_sp:
             if dec_sp.recording:
-                # the fused decode's update width
-                dec_sp.set(W=self.strategy._bucket(rows, self.slots))
+                # the fused decode's update width, the sequences it
+                # serves, the live tokens they attend over, and the
+                # tokens its table gather covers (paged disciplines)
+                dec_sp.set(W=self.strategy._bucket(rows, self.slots),
+                           seqs=len(self.running),
+                           kv_tokens=sum(r.tokens_held
+                                         for r in self.running.values()))
+                table = self.strategy.table_tokens
+                if table is not None:
+                    dec_sp.set(table_tokens=table)
             if self.resident_tables:
                 if self._tok_dirty or self._tok_dev is None:
                     tok_dev = jnp.asarray(self._next_tok)

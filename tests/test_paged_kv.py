@@ -57,6 +57,43 @@ def test_prefill_equals_appends(rng):
                                   np.asarray(cache2.seq_lens))
 
 
+@pytest.mark.parametrize("width, per_row", [(288, 4), (40, 8)])
+def test_packed_latent_pool_prefill_append_gather(rng, width, per_row):
+    """A single latent stream packs ``per_row`` tokens to a pool row
+    (MiniCPM3's 288-wide latent four to 1,152 lanes; a width no divisor
+    of the block brings to 128 lanes, the whole block in one row).  A
+    prefill and appends land where the table says, group-local ids under
+    two data-parallel groups land as pool-wide ids do, and the gather
+    returns every token in order."""
+    L, B, bt, T0, T = 2, 4, 8, 16, 20
+    pools = []
+    for dp in (1, 2):
+        cfg = PagedKVConfig(num_layers=L, kv_heads=1, head_dim=width,
+                            block_tokens=bt, num_blocks=16,
+                            max_blocks_per_seq=4, latent=True,
+                            dtype=jnp.float32, dp_groups=dp)
+        assert cfg.packed and cfg.tokens_per_row == per_row
+        assert cfg.pool_shape() == (L, 16, bt // per_row, per_row * width)
+        ids = np.arange(16).reshape(B, 4)[:, ::-1].copy()  # pool-wide
+        if dp == 2:
+            ids[2:] -= 8                                    # group-local
+        cache = dataclasses.replace(PagedKVCache.create(cfg, B),
+                                    block_tables=jnp.asarray(ids))
+        lat = np.random.RandomState(7).randn(L, B, T, width).astype(
+            np.float32)
+        cache = cache.write_prefill(jnp.asarray(lat[:, :, :T0]), None,
+                                    jnp.full((B,), T0, jnp.int32))
+        for t in range(T0, T):
+            cache = cache.append_token(jnp.asarray(lat[:, :, t]), None)
+        pools.append(np.asarray(cache.k_pool))
+        if dp == 1:
+            for layer in range(L):
+                got, _ = cache.gather_layer(cache.k_pool[layer], None)
+                np.testing.assert_array_equal(np.asarray(got)[:, :T],
+                                              lat[layer])
+    np.testing.assert_array_equal(pools[0], pools[1])
+
+
 def test_manager_admission_by_blocks():
     cfg = PagedKVConfig(num_layers=1, kv_heads=1, head_dim=4,
                         block_tokens=8, num_blocks=4, max_blocks_per_seq=4)

@@ -196,6 +196,51 @@ def test_phase_time_keeps_the_step_boundaries(family, families, ticks):
     assert_engine_quiescent(eng)
 
 
+@pytest.mark.parametrize("family", ["paged", "ssm"])
+def test_translation_counts_on_the_step_spans(family, families):
+    """With capture on, each step's ``serve.decode`` carries the live
+    tokens its sequences attend over (their ``tokens_held`` at the
+    decode, one less than after it) and, for the paged discipline, the
+    tokens its table gather covers; ``serve.sync`` carries the rows and
+    bytes ``Engine`` adds to ``table_rows_updated`` and
+    ``table_sync_bytes``; ``serve.grow`` the blocks grown, one for each
+    sequence whose next token starts a block.  The constant-state
+    discipline keeps its spans, with no ``table_tokens``."""
+    cfg, model, params = families[family]
+    eng = _engine(model, params, num_blocks=24 if family == "paged" else 4)
+    paged = family == "paged"
+    bt = eng.strategy.block_tokens
+    rng = np.random.RandomState(5)
+    for rid, n in enumerate((7, 8, 13)):
+        eng.submit(_request(cfg, rid, rng, n=n, max_new=10))
+    decodes = 0
+    while eng.running or eng.sched.has_work:
+        n_done = len(eng.done)
+        rows0, bytes0 = eng.table_rows_updated, eng.table_sync_bytes
+        eng.telemetry.capture(True)
+        eng.step()
+        recs = {r["name"]: r for r in eng.telemetry.capture(False)}
+        assert {"serve.step", "serve.sync", "serve.decode",
+                "serve.retire"} <= set(recs)
+        held = [r.tokens_held - 1 for r in
+                list(eng.running.values()) + eng.done[n_done:]]
+        dec, sync = recs["serve.decode"], recs["serve.sync"]
+        assert dec["kv_tokens"] == sum(held) and dec["seqs"] == len(held)
+        if paged:
+            kv = eng.cache.config
+            assert dec["table_tokens"] == (eng.slots * kv.max_blocks_per_seq
+                                           * kv.block_tokens)
+        else:
+            assert "table_tokens" not in dec
+        assert sync["rows"] == eng.table_rows_updated - rows0
+        assert sync["bytes"] == eng.table_sync_bytes - bytes0
+        want = sum(1 for h in held if h % bt == 0) if paged else 0
+        assert recs["serve.grow"]["blocks"] == want
+        decodes += 1
+    assert decodes > 0 and eng.table_sync_bytes > 0
+    assert_engine_quiescent(eng)
+
+
 def test_admission_stamp_set_once_not_on_resume(families):
     cfg, model, params = families["paged"]
     eng = _engine(model, params, num_blocks=32)
@@ -256,4 +301,8 @@ def test_spans_land_on_the_host_plane(families, tmp_path):
     assert found["serve.prefill"]["seqs"] == 1
     assert found["serve.prefill"]["tokens"] == 9
     assert found["serve.decode"]["W"] >= 1
+    # the first request holds 10 tokens (9 and its first), the second 9
+    assert found["serve.decode"]["kv_tokens"] == 19
+    assert found["serve.sync"]["rows"] >= 1
+    assert found["serve.sync"]["bytes"] > 0
     assert_engine_quiescent(eng)

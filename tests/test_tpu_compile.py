@@ -128,6 +128,43 @@ def test_fused_decode_step_fits_one_chip(one_chip):
     assert _peak(compiled) < HBM_BYTES, compiled.memory_analysis()
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill", "swap_out"])
+def test_minicpm3_chat_programs_fit_one_chip(one_chip, program):
+    """The ``minicpm3-chat`` cell's serving settings: MiniCPM3-4B as
+    published (its own head), 64 slots of 2,048 tokens over 2,048 latent
+    blocks, so every slot can hold ``max_seq``.  The fused decode at the
+    widest table update, a prefill of 1,024 tokens and the swap-out block
+    gather, each with its pool donated, fit what the compiler grants one
+    program (15.75 GiB): the packed latent pool is written in place, with
+    no second copy of it."""
+    model = build_model(get_config("minicpm3_4b_published"),
+                        max_positions=2048)
+    params, _ = model.param_specs()
+    params = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), params)
+    B = 64
+    kv = model.kv_config(max_seq=2048, num_blocks=2048, batch=B)
+    assert kv.packed and kv.tokens_per_row == 4
+    i32 = lambda *shape: _sds(shape, jnp.int32, one_chip)
+    if program == "decode":
+        cache = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                             PagedKVCache.specs(kv, B))
+        args = (params, i32(B), cache, i32(B), i32(B, kv.max_blocks_per_seq),
+                i32(B))
+        fn = model._decode_fused_impl
+    elif program == "prefill":
+        cache = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                             PagedKVCache.specs(kv, 1))
+        args = (params, {"tokens": i32(1, 1024)}, cache, i32(1))
+        fn = model._prefill_impl
+    else:
+        args = (_sds(kv.pool_shape(), kv.dtype, one_chip),
+                i32(kv.max_blocks_per_seq))
+        fn = bc.gather_blocks
+    donate = () if program == "swap_out" else (2,)
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    assert _peak(compiled) < 15.75 * 2**30, compiled.memory_analysis()
+
+
 def test_sharded_serve_step_compiles_on_four_chips(topo):
     """``chip_smoke.py --four-chips``: the (data=1, model=4) serve step
     spreads the parameters (about a quarter on each chip)."""
