@@ -35,6 +35,40 @@ def _dma_copy_kernel(src_ref, dst_ref, pool_ref, out_ref, sem):
     copy.wait()
 
 
+def _row_copies(rows_ref, start_ref, pool_ref, buf_ref, sem, *, to_pool):
+    """One DMA a row ``b`` between ``pool_ref[rows[b], start:start + n]``
+    and ``buf_ref[b]`` (into the pool if ``to_pool``): all started, then
+    all waited on one semaphore, each wait taking one copy's bytes."""
+    n = buf_ref.shape[1]
+    start = pl.multiple_of(start_ref[0], 8)
+
+    def copy(b):
+        piece = pool_ref.at[rows_ref[b], pl.ds(start, n)]
+        src, dst = ((buf_ref.at[b], piece) if to_pool
+                    else (piece, buf_ref.at[b]))
+        return pltpu.make_async_copy(src, dst, sem)
+
+    def launch(b, carry):
+        copy(b).start()
+        return carry
+
+    def wait(b, carry):
+        copy(b).wait()
+        return carry
+
+    jax.lax.fori_loop(0, rows_ref.shape[0], launch, 0)
+    jax.lax.fori_loop(0, rows_ref.shape[0], wait, 0)
+
+
+def _row_gather_kernel(rows_ref, start_ref, pool_ref, out_ref, sem):
+    _row_copies(rows_ref, start_ref, pool_ref, out_ref, sem, to_pool=False)
+
+
+def _row_scatter_kernel(rows_ref, start_ref, upd_ref, pool_ref, out_ref,
+                        sem):
+    _row_copies(rows_ref, start_ref, out_ref, upd_ref, sem, to_pool=True)
+
+
 def _gather_kernel(idx_ref, pool_ref, out_ref):
     out_ref[...] = pool_ref[...]
 
@@ -52,9 +86,10 @@ def block_copy(pool: jax.Array, src: jax.Array, dst: jax.Array,
     (52 MB for Mamba2-370M), far beyond what VMEM staging can hold.
     Output aliases the pool, so untouched blocks stay in place.  On TPU
     each block must be one contiguous tile run: the pool is laid out
-    ``(NB, 1, E)`` with E a multiple of 128 (see
-    ``serve.arch.ConstantStateManager``); Mosaic refuses a single row of
-    an ``(NB, E)`` array, whose (8, 128) tiles interleave 8 rows.
+    ``(NB, 1, E)`` with E a multiple of 128, or in rows, ``(NB, E / 128,
+    128)`` (see ``serve.arch.ConstantStateManager``); Mosaic refuses a
+    single row of an ``(NB, E)`` array, whose (8, 128) tiles interleave
+    8 rows.
     """
     n = src.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -162,3 +197,51 @@ def copy_pool_blocks(pool: jax.Array, src: jax.Array, dst: jax.Array,
         interpret=interpret,
         input_output_aliases={2: 0},
     )(src, dst, pool)
+
+
+def _row_spec(n_in: int):
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(1,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n_in,
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+    )
+
+
+def gather_rows(pool: jax.Array, rows: jax.Array, start: jax.Array, n: int,
+                *, interpret: bool = False) -> jax.Array:
+    """pool: (NB, R, 128); rows: (B,) int32; start: int32 scalar ->
+    ``pool[rows, start:start + n]``, (B, n, 128).
+
+    One HBM-to-HBM DMA a row, all in flight at once: the read of one
+    layer's recurrent state for every slot of the layered state decode
+    (``Mamba2LM.decode_step_in_pool``).  ``start`` and ``n`` must be
+    multiples of 8, so each piece is a contiguous run of (8, 128) tiles.
+    Rows may repeat.
+    """
+    return pl.pallas_call(
+        _row_gather_kernel,
+        grid_spec=_row_spec(1),
+        out_shape=jax.ShapeDtypeStruct((rows.shape[0], n, pool.shape[2]),
+                                       pool.dtype),
+        interpret=interpret,
+        name="state_row_gather",
+    )(rows, jnp.reshape(start, (1,)).astype(jnp.int32), pool)
+
+
+def scatter_rows(pool: jax.Array, rows: jax.Array, start: jax.Array,
+                 upd: jax.Array, *, interpret: bool = False) -> jax.Array:
+    """The inverse of ``gather_rows``: ``pool.at[rows, start:start +
+    n].set(upd)`` for upd (B, n, 128), one DMA a row into the pool, which
+    the output aliases, so nothing else of the pool moves.  The copies
+    of a repeated row (slots padded to the sink) race, and leave that
+    piece undefined; on the interpreter the last one lands."""
+    return pl.pallas_call(
+        _row_scatter_kernel,
+        grid_spec=_row_spec(2),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        interpret=interpret,
+        input_output_aliases={3: 0},
+        name="state_row_scatter",
+    )(rows, jnp.reshape(start, (1,)).astype(jnp.int32), upd, pool)

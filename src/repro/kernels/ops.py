@@ -121,6 +121,20 @@ def scatter_blocks(pool, idx, payload, interpret: Optional[bool] = None):
                               interpret=_use_interpret(interpret))
 
 
+def gather_rows(pool, rows, start, n: int, interpret: Optional[bool] = None):
+    """``pool[rows, start:start + n]`` of a (NB, R, 128) pool, one DMA a
+    row (the layered state decode's read of one layer)."""
+    return _bc.gather_rows(pool, rows, start, n,
+                           interpret=_use_interpret(interpret))
+
+
+def scatter_rows(pool, rows, start, upd, interpret: Optional[bool] = None):
+    """``pool.at[rows, start:start + n].set(upd)`` in place, one DMA a row
+    (the inverse of ``gather_rows``)."""
+    return _bc.scatter_rows(pool, rows, start, upd,
+                            interpret=_use_interpret(interpret))
+
+
 # re-export oracles for convenience
 tree_gather_ref = kref.tree_gather_ref
 tree_block_sum_ref = kref.tree_block_sum_ref

@@ -6,6 +6,12 @@ fixed-size Arena block per sequence, zero growth) instead of paged KV:
 the `ConstantStateStrategy` in ``serve/arch.py``.  Prefill masks right
 padding exactly (``mamba2_fwd(lengths=...)``), so a padded batched
 prefill is token-identical to per-sequence prefill.
+
+Where its widths allow, one sequence's state block is laid out in
+128-lane rows (``state_block``): the conv windows of every layer, then
+each layer's SSD state as a run of whole rows, so the served decode
+(``decode_step_in_pool``) reads and writes each layer's state in place
+inside the layer scan instead of relaying whole rows in and out.
 """
 
 from __future__ import annotations
@@ -17,11 +23,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 from repro.launch.shardings import constrain
 from repro.models import mamba2 as M2
 from repro.models.common import (AxTree, Params, chunked_lm_loss,
                                  dense_init, rmsnorm)
 from repro.models.lm import _stack_axes, eval_shape_with_aux
+
+LANES = 128                   # lanes of one row of a layered state block
+TILE = 8 * LANES              # elements of one (8, 128) tile
 
 
 @jax.tree_util.register_pytree_node_class
@@ -133,21 +143,64 @@ class Mamba2LM:
         last = jnp.take_along_axis(logits, idx[:, None, None], axis=1)[:, 0]
         return last, states
 
-    def decode_step(self, p: Params, tokens: jax.Array, state: Mamba2State):
-        cfg = self.cfg
-        x = p["embed"][tokens]
+    def _layer_step(self, lp, x, cs, ss):
+        h = rmsnorm(x, lp["ln"], self.cfg.norm_eps, gemma_style=True)
+        y, (cs, ss) = M2.mamba2_step(lp["mamba"], h, self.cfg, cs, ss)
+        return x + y, cs, ss
 
+    def _logits(self, p, x):
+        cfg = self.cfg
+        return (rmsnorm(x, p["final_norm"], cfg.norm_eps, gemma_style=True)
+                @ p["embed"].T).astype(jnp.float32)
+
+    def decode_step(self, p: Params, tokens: jax.Array, state: Mamba2State):
         def body(x, xs):
             lp, cs, ss = xs
-            h = rmsnorm(x, lp["ln"], cfg.norm_eps, gemma_style=True)
-            y, (cs, ss) = M2.mamba2_step(lp["mamba"], h, cfg, cs, ss)
-            return x + y, (cs, ss)
+            x, cs, ss = self._layer_step(lp, x, cs, ss)
+            return x, (cs, ss)
 
         x, (conv, ssd) = jax.lax.scan(
-            body, x, (p["layers"], state.conv, state.ssd))
-        logits = (rmsnorm(x, p["final_norm"], cfg.norm_eps, gemma_style=True)
-                  @ p["embed"].T).astype(jnp.float32)
-        return logits, Mamba2State(conv, ssd)
+            body, p["embed"][tokens], (p["layers"], state.conv, state.ssd))
+        return self._logits(p, x), Mamba2State(conv, ssd)
+
+    def decode_step_in_pool(self, p: Params, tokens: jax.Array,
+                            pool: jax.Array, rows: jax.Array):
+        """``decode_step`` on the states held in blocks ``rows`` of a
+        ``(NB, *state_block)`` pool, written back in place -> (logits,
+        pool).  The conv windows are gathered once and written back after
+        the layer scan; inside the scan, which carries the pool, each
+        layer's SSD state is read with one DMA a row (``ops.gather_rows``),
+        stepped, and written back the same way (``ops.scatter_rows``), so
+        no op outputs a pool-sized copy.  Rows may repeat (slots padded to
+        one sink block): every read of a piece precedes its writes."""
+        cfg = self.cfg
+        d_inner, H, P, N, W = M2._dims(cfg)
+        L, B = cfg.num_layers, rows.shape[0]
+        cd = d_inner + 2 * N
+        conv_rows = L * (W - 1) * cd // LANES
+        ssd_rows = H * P * N // LANES
+        with jax.named_scope("state_gather"):
+            conv = pool[rows, :conv_rows].reshape(B, L, W - 1, cd)
+            conv = jnp.moveaxis(conv, 1, 0)
+
+        def body(carry, xs):
+            x, pool = carry
+            lp, cs, layer = xs
+            start = conv_rows + layer * ssd_rows
+            ss = ops.gather_rows(pool, rows, start, ssd_rows)
+            x, cs, ss = self._layer_step(lp, x, cs, ss.reshape(B, H, P, N))
+            pool = ops.scatter_rows(pool, rows, start,
+                                    ss.reshape(B, ssd_rows, LANES))
+            return (x, pool), cs
+
+        with jax.named_scope("layer_scan"):
+            (x, pool), conv = jax.lax.scan(
+                body, (p["embed"][tokens], pool),
+                (p["layers"], conv, jnp.arange(L, dtype=jnp.int32)))
+        with jax.named_scope("state_writeback"):
+            pool = pool.at[rows, :conv_rows].set(
+                jnp.moveaxis(conv, 0, 1).reshape(B, conv_rows, LANES))
+        return self._logits(p, x), pool
 
     # -- constant-state pool glue (serve/arch.ConstantStateStrategy) --
     @property
@@ -158,11 +211,33 @@ class Mamba2LM:
         L = self.cfg.num_layers
         return L * ((W - 1) * (d_inner + 2 * N) + H * P * N)
 
+    @property
+    def state_block(self) -> Optional[Tuple[int, int]]:
+        """``(rows, LANES)``: one state block in 128-lane rows, in
+        ``state_to_rows``'s element order -- the conv windows of every
+        layer, then layer l's SSD state as ``H * P * N / LANES`` rows.
+        None unless the conv windows and each layer's SSD state fill whole
+        (8, 128) tiles, so that each is one contiguous run of tiles that a
+        DMA moves as one piece: the pool then stays flat."""
+        d_inner, H, P, N, W = M2._dims(self.cfg)
+        conv = self.cfg.num_layers * (W - 1) * (d_inner + 2 * N)
+        if conv % TILE or (H * P * N) % TILE:
+            return None
+        return self.state_elems // LANES, LANES
+
     def state_to_rows(self, state: Mamba2State) -> jax.Array:
         """Flatten the (L, B, ...) state to (B, state_elems) rows."""
         B = state.conv.shape[1]
         c = jnp.moveaxis(state.conv, 1, 0).reshape(B, -1)
         s = jnp.moveaxis(state.ssd, 1, 0).reshape(B, -1)
+        return jnp.concatenate([c, s], axis=1).astype(jnp.float32)
+
+    def state_to_blocks(self, state: Mamba2State) -> jax.Array:
+        """``state_to_rows`` laid out as (B, *state_block) blocks, built
+        in rows with no flat (B, state_elems) array between."""
+        B = state.conv.shape[1]
+        c = jnp.moveaxis(state.conv, 1, 0).reshape(B, -1, LANES)
+        s = jnp.moveaxis(state.ssd, 1, 0).reshape(B, -1, LANES)
         return jnp.concatenate([c, s], axis=1).astype(jnp.float32)
 
     def rows_to_state(self, rows: jax.Array) -> Mamba2State:

@@ -66,26 +66,34 @@ class ConstantStateManager:
     The SSM/linear-attention analogue of ``PagedKVManager``: every
     sequence owns exactly ONE block of ``state_elems`` float32 elements
     (the flattened recurrent state), allocated at admission and never
-    grown.  The device stream is a flat ``(num_blocks, 1, state_elems)``
-    pool registered with the transfer plane (``layered=False``), so
-    swap-out/swap-in/prefetch/compaction all ride the same plans and
-    kernels as paged KV -- one block per sequence just makes every move
-    trivially sized.
+    grown.  The device stream is a ``(num_blocks, *block)`` pool
+    registered with the transfer plane as one flat stream
+    (``layered=False``), so swap-out/swap-in/prefetch/compaction all ride
+    the same plans and kernels as paged KV -- one block per sequence just
+    makes every move trivially sized.  ``block`` is ``(1, state_elems)``
+    unless the model lays its state out in rows (``state_block``, e.g.
+    ``(E / 128, 128)`` for Mamba-2); either way a block holds the same
+    elements in the same order, so payloads and snapshots carry the same
+    bytes.
     """
 
     def __init__(self, arena: Arena, pool_class: str, state_elems: int,
-                 num_blocks: int):
+                 num_blocks: int, block: Optional[Tuple[int, ...]] = None):
         if state_elems <= 0:
             raise ValueError("state_elems must be positive")
+        # (NB, 1, E), not (NB, E): on TPU a 2-D array's (8, 128) tiles
+        # interleave 8 rows, so one block would not be contiguous and
+        # block_copy could not move it as one DMA
+        block = tuple(block) if block is not None else (1, state_elems)
+        if math.prod(block) != state_elems:
+            raise ValueError(f"block {block} does not hold {state_elems} "
+                             f"elements")
         self.arena = arena
         self.state_elems = state_elems
         self.pool_class = arena.register_class(
             pool_class, num_blocks=num_blocks,
             block_shape=(state_elems,), dtype=np.float32)
-        # (NB, 1, E), not (NB, E): on TPU a 2-D array's (8, 128) tiles
-        # interleave 8 rows, so one block would not be contiguous and
-        # block_copy could not move it as one DMA
-        self.pool = jnp.zeros((num_blocks, 1, state_elems), jnp.float32)
+        self.pool = jnp.zeros((num_blocks, *block), jnp.float32)
         self._maps: Dict[int, Mapping] = {}
         arena.transfers.register_executor(
             self.pool_class, self._streams, self._set_streams,
@@ -272,6 +280,8 @@ class CacheStrategy:
     supports_suffix_prefill = False
     #: engine-set: device tables/rows are resident and delta-maintained
     resident = False
+    #: the decode reads and writes each layer's state in place
+    layered = False
 
     # -- admission view (scheduler vector path) --
     def footprint(self, req) -> Dict[str, int]:
@@ -829,16 +839,49 @@ class PagedKVStrategy(CacheStrategy):
         self.arena.check_registry(self.mgr.pool_class)
 
 
+def _state_step(model, p, tokens, pool, rows):
+    """Decode the states of pool blocks ``rows`` and write the new states
+    back -> (logits, pool).  Written back every step: the pool stays
+    authoritative, so a later swap-out gather always reads the current
+    state."""
+    if getattr(model, "state_block", None) is not None:
+        return model.decode_step_in_pool(p, tokens, pool, rows)
+    state = model.rows_to_state(pool[rows, 0])
+    logits, new_state = model.decode_step(p, tokens, state)
+    return logits, pool.at[rows, 0].set(model.state_to_rows(new_state))
+
+
+def constant_state_decode_fn(model):
+    """The constant-state discipline's fused decode: one jitted,
+    pool-donated trace of row delta-scatter -> decode step with the state
+    read and written back (``_state_step``) -> argmax.  The row index
+    vector stays latched on device between steps."""
+
+    def constant_state_decode(p, tokens, pool, rows, upd_slots, upd_rows):
+        rows = rows.at[upd_slots].set(upd_rows, mode="drop")
+        logits, pool = _state_step(model, p, tokens, pool, rows)
+        return jnp.argmax(logits, axis=-1), pool, rows
+
+    return jax.jit(constant_state_decode, donate_argnums=(2,))
+
+
 class ConstantStateStrategy(CacheStrategy):
     """Fixed-size recurrent state, one block per sequence (SSM models).
 
-    The pool IS the authoritative device state: every decode gathers
-    each running slot's state row, steps the model, and scatters the
-    new rows back -- so a swap-out gather at any step boundary reads
-    the current state, and a resume is one block's scatter.  Footprint
-    is EXACT (1 block, never grows): admission applies no watermark to
-    this class, and preemption of one sequence always frees exactly
-    what the next admission of its kind needs.
+    The pool IS the authoritative device state: every decode reads each
+    running slot's state, steps the model, and writes the new state
+    back -- so a swap-out gather at any step boundary reads the current
+    state, and a resume is one block's scatter.  Footprint is EXACT (1
+    block, never grows): admission applies no watermark to this class,
+    and preemption of one sequence always frees exactly what the next
+    admission of its kind needs.
+
+    A model that says where each layer's state lives in a block
+    (``state_block``, with ``decode_step_in_pool``) gets a layered pool:
+    its decode reads and writes each layer's state in place inside the
+    layer scan (``layered`` True).  Any other gets the flat ``(NB, 1,
+    E)`` pool, whose decode gathers whole rows, relays them into the
+    model's state (``rows_to_state``) and scatters them back.
     """
 
     def __init__(self, model, *, arena: Arena, slots: int, max_seq: int,
@@ -849,8 +892,11 @@ class ConstantStateStrategy(CacheStrategy):
         self.model = model
         self.arena = arena
         self.slots = slots
+        block = getattr(model, "state_block", None)
+        self.layered = block is not None
         self.mgr = ConstantStateManager(arena, pool_prefix + "state",
-                                        model.state_elems, num_blocks)
+                                        model.state_elems, num_blocks,
+                                        block=block)
         self._sink = self.mgr.reserve_sink()
         self.store = HostBlockStore(arena, self.mgr.pool_class)
         self.pool_classes = [self.mgr.pool_class]
@@ -950,12 +996,8 @@ class ConstantStateStrategy(CacheStrategy):
 
     def decode(self, params, tokens):
         idx = jnp.asarray(self._rows, jnp.int32)
-        state = self.model.rows_to_state(self.mgr.pool[idx, 0])
-        logits, new_state = self.model.decode_step(params, tokens, state)
-        # scatter back every step: the pool stays authoritative, so a
-        # later swap-out gather always reads the current state
-        self.mgr.pool = self.mgr.pool.at[idx, 0].set(
-            self.model.state_to_rows(new_state))
+        logits, self.mgr.pool = _state_step(self.model, params, tokens,
+                                            self.mgr.pool, idx)
         return logits
 
     def full_sync_cost(self):
@@ -980,22 +1022,8 @@ class ConstantStateStrategy(CacheStrategy):
         return len(upd), (upd_slots.nbytes + upd_rows.nbytes if W else 0)
 
     def _fused_fn(self):
-        """One jitted, pool-donated trace: row delta-scatter -> state
-        gather -> decode step -> state scatter-back -> argmax.  The row
-        index vector stays latched on device between steps."""
         if self._fused is None:
-            model = self.model
-
-            def constant_state_decode(p, tokens, pool, rows, upd_slots,
-                                      upd_rows):
-                rows = rows.at[upd_slots].set(upd_rows, mode="drop")
-                state = model.rows_to_state(pool[rows, 0])
-                logits, new_state = model.decode_step(p, tokens, state)
-                pool = pool.at[rows, 0].set(model.state_to_rows(new_state))
-                return jnp.argmax(logits, axis=-1), pool, rows
-
-            self._fused = jax.jit(constant_state_decode,
-                                  donate_argnums=(2,))
+            self._fused = constant_state_decode_fn(self.model)
         return self._fused
 
     def decode_resident(self, params, tokens):
@@ -1010,15 +1038,17 @@ class ConstantStateStrategy(CacheStrategy):
     def _prefill_fn(self):
         """One jitted, pool-donated program per (rows, width): zero state
         -> padded prefill -> argmax of each row's last logits -> state
-        scatter into the pool rows, in place."""
+        scatter into the pool blocks, in place, in the pool's layout."""
         if self._prefill is None:
             model = self.model
+            to_blocks = (model.state_to_blocks if self.layered
+                         else lambda st: model.state_to_rows(st)[:, None])
 
             def constant_state_prefill(p, toks, lengths, pool, idx):
                 state0 = model.init_state(toks.shape[0])
                 last, state = model.prefill(p, {"tokens": toks}, state0,
                                             lengths)
-                pool = pool.at[idx, 0].set(model.state_to_rows(state))
+                pool = pool.at[idx].set(to_blocks(state))
                 return jnp.argmax(last, axis=-1), pool
 
             self._prefill = jax.jit(constant_state_prefill,

@@ -728,12 +728,14 @@ class Engine:
         with tel.span("serve.decode") as dec_sp:
             if dec_sp.recording:
                 # the fused decode's update width, the sequences it
-                # serves, the live tokens they attend over, and the
-                # tokens its table gather covers (paged disciplines)
+                # serves, the live tokens they attend over, whether it
+                # updates state in place layer by layer, and the tokens
+                # its table gather covers (paged disciplines)
                 dec_sp.set(W=self.strategy._bucket(rows, self.slots),
                            seqs=len(self.running),
                            kv_tokens=sum(r.tokens_held
-                                         for r in self.running.values()))
+                                         for r in self.running.values()),
+                           layered=int(self.strategy.layered))
                 table = self.strategy.table_tokens
                 if table is not None:
                     dec_sp.set(table_tokens=table)

@@ -370,3 +370,26 @@ def test_scatter_blocks_inverse_of_gather(layers, nb, blk, rng):
     np.testing.assert_array_equal(np.asarray(out), ref)
     back = gather_blocks(out, jnp.asarray(ids), interpret=True)
     np.testing.assert_array_equal(np.asarray(back), np.asarray(payload))
+
+
+@pytest.mark.parametrize("nb,rows_per_block,start,n,rows", [
+    (5, 32, 8, 16, [3, 0, 4]), (4, 24, 0, 24, [2, 2, 1]),
+    (6, 40, 16, 8, [5, 1, 0, 3])])
+def test_gather_scatter_rows_in_place(nb, rows_per_block, start, n, rows,
+                                      rng):
+    """The layered state decode's row DMAs: ``gather_rows`` reads
+    ``pool[rows, start:start + n]``; ``scatter_rows`` writes it back in
+    place and leaves every other element of the pool as it was; on the
+    interpreter a repeated row takes the last piece."""
+    from repro.kernels.block_copy import gather_rows, scatter_rows
+    pool = jnp.asarray(rng.randn(nb, rows_per_block, 128).astype(np.float32))
+    idx = jnp.asarray(np.array(rows, np.int32))
+    got = gather_rows(pool, idx, jnp.int32(start), n, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(pool)[rows, start:start + n])
+    upd = jnp.asarray(rng.randn(len(rows), n, 128).astype(np.float32))
+    out = scatter_rows(pool, idx, jnp.int32(start), upd, interpret=True)
+    ref = np.asarray(pool).copy()
+    for i, r in enumerate(rows):
+        ref[r, start:start + n] = np.asarray(upd)[i]
+    np.testing.assert_array_equal(np.asarray(out), ref)

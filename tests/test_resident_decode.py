@@ -16,6 +16,8 @@ device-side shadow (tables/rows vs the manager's truth) and would trip
 on any mapping mutation that forgot to mark its slot dirty.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
@@ -23,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import get_config
 from repro.models.api import build_model
+from repro.models.mamba2_lm import Mamba2LM
 from repro.serve.engine import Engine, Request
 from conftest import assert_engine_quiescent
 
@@ -30,11 +33,17 @@ from conftest import assert_engine_quiescent
 @pytest.fixture(scope="module")
 def families():
     """One tiny model per discipline: paged / constant / composite, and
-    rwkv6 beside mamba2 on the constant-state discipline."""
+    rwkv6 beside mamba2 on the constant-state discipline.  ``ssm_tiles``
+    is mamba2 with the published model's 128-wide SSD state, so its state
+    fills whole (8, 128) tiles and is served from the layered pool."""
     out = {}
     for key, name in (("dense", "gemma_2b"), ("ssm", "mamba2_370m"),
-                      ("hybrid", "zamba2_2p7b"), ("rwkv6", "rwkv6_7b")):
+                      ("hybrid", "zamba2_2p7b"), ("rwkv6", "rwkv6_7b"),
+                      ("ssm_tiles", "mamba2_370m")):
         cfg = get_config(name).reduced()
+        if key == "ssm_tiles":
+            cfg = dataclasses.replace(
+                cfg, ssm=dataclasses.replace(cfg.ssm, state_dim=128))
         model = build_model(cfg)
         params, _ = model.init(jax.random.PRNGKey(hash(key) % 2**31))
         out[key] = (cfg, model, params)
@@ -150,9 +159,9 @@ def test_resident_identity_across_external_compaction(families, rng):
 
 
 def test_constant_state_compaction_rides_block_copy(families):
-    """``compact_now()`` on the flat ``(NB, 1, E)`` state pool moves
-    whole sequence states with the ``block_copy`` kernel, and every
-    sequence decodes exactly as it does without the move."""
+    """``compact_now()`` on the state pool moves whole sequence states
+    with the ``block_copy`` kernel, and every sequence decodes exactly as
+    it does without the move."""
     cfg, model, params = families["ssm"]
     prompts = _prompts(cfg, seed=11, n=3, shared=False)
     max_new = [2, 8, 8]          # the early finisher leaves a hole
@@ -175,6 +184,80 @@ def test_constant_state_compaction_rides_block_copy(families):
         got[compact] = {r.rid: list(r.generated) for r in eng.done}
         assert_engine_quiescent(eng)
     assert got[True] == got[False]
+
+
+class _FlatMamba2(Mamba2LM):
+    """Mamba-2 that does not say where each layer's state lives in a
+    block: served from the flat ``(NB, 1, E)`` pool."""
+    state_block = None
+
+
+def _pool_bits(eng):
+    pool = np.asarray(eng.mgr.pool)
+    return pool.reshape(pool.shape[0], -1).view(np.uint32)
+
+
+@pytest.mark.parametrize("resident", [True, False])
+def test_layered_state_decode_matches_flat(families, resident):
+    """The in-place, layer-by-layer decode against the flat row path,
+    step for step, fused and eager: slots join and leave (sink-padded
+    slots between), one sequence is swapped out and back in, one
+    compaction ``block_copy`` runs.  The tokens are identical, and after
+    every step so is the pool, byte for byte: live blocks, the sink and
+    untouched blocks."""
+    cfg, model, params = families["ssm_tiles"]
+    assert model.state_elems % 1024 == 0
+    engs = [_engine(m, params, resident, slots=3, num_blocks=8)
+            for m in (model, _FlatMamba2(cfg))]
+    layered, flat = engs
+    assert layered.strategy.layered and not flat.strategy.layered
+    assert layered.mgr.pool.shape[1:] == model.state_block
+    assert flat.mgr.pool.shape[1:] == (1, model.state_elems)
+    max_new = (3, 9, 6, 4, 7)
+    for eng in engs:
+        prng = np.random.RandomState(17)
+        for rid, n in enumerate((9, 14, 6, 11, 17)):
+            eng.submit(Request(rid=rid, max_new=max_new[rid],
+                               prompt=prng.randint(2, cfg.vocab_size,
+                                                   size=n)))
+    forced = compacted = padded = False
+    while (layered.sched.has_work or layered.running) and layered.steps < 200:
+        for eng in engs:
+            eng.step()
+            eng.check_consistency()
+        padded |= 0 < len(layered.running) < layered.slots
+        if layered.steps == 3 and not forced:
+            for eng in engs:
+                eng.preempt_latest()
+            forced = True
+        if (forced and not compacted and layered.arena.fragmentation(
+                layered.mgr.pool_class) > 0):
+            assert [eng.compact_now() for eng in engs][0] > 0
+            compacted = True
+        np.testing.assert_array_equal(_pool_bits(layered), _pool_bits(flat))
+    for eng in engs:
+        eng.sync_transfers()
+        assert len(eng.done) == len(max_new)
+        assert eng.stats["swap_outs"] >= 1 and eng.stats["swap_ins"] >= 1
+        launches = eng.stats["transfers"]["kernel_launches"]
+        assert launches.get("block_copy", 0) >= 1, launches
+        assert_engine_quiescent(eng)
+    assert forced and compacted and padded
+    assert ({r.rid: list(r.generated) for r in layered.done}
+            == {r.rid: list(r.generated) for r in flat.done})
+
+
+@pytest.mark.parametrize("family", ["rwkv6", "hybrid"])
+def test_other_constant_state_models_keep_the_flat_pool(families, family):
+    """Only a model that says where each layer's state lives in a block
+    gets the layered pool: RWKV6 and the Zamba2 hybrid keep ``(NB, 1,
+    E)`` and the row-relaying decode."""
+    cfg, model, params = families[family]
+    eng = _engine(model, params, True)
+    mgr = eng.strategy.state_mgr if family == "hybrid" else eng.mgr
+    assert getattr(model, "state_block", None) is None
+    assert not eng.strategy.layered
+    assert mgr.pool.shape[1:] == (1, model.state_elems)
 
 
 def test_resident_migrate_live_token_identity(families, tmp_path):
@@ -269,7 +352,7 @@ def test_resident_steady_state_stops_uploading(families, rng):
     assert list(eng2.done[0].generated) == list(eng.done[0].generated)
 
 
-@pytest.mark.parametrize("family", ["ssm", "rwkv6"])
+@pytest.mark.parametrize("family", ["ssm", "rwkv6", "ssm_tiles"])
 def test_constant_state_batched_prefill_matches_per_sequence(families,
                                                              family):
     """The jitted, pool-donated prefill of rows whose lengths straddle two
@@ -291,7 +374,8 @@ def test_constant_state_batched_prefill_matches_per_sequence(families,
     strat.prefill(params, [(0, live, 0)])     # a live sequence's state
 
     def rows(ids):
-        return np.asarray(strat.mgr.pool[jnp.asarray(ids, jnp.int32), 0])
+        blocks = strat.mgr.pool[jnp.asarray(ids, jnp.int32)]
+        return np.asarray(blocks).reshape(len(ids), -1)
 
     kept = [strat.mgr.row(live.rid), strat.sink]
     before = rows(kept)

@@ -3,6 +3,7 @@
 step loop's phase boundaries, the admission stamp, and the spans' place
 on the host plane of a profiler trace."""
 
+import dataclasses
 import glob
 import itertools
 
@@ -31,8 +32,12 @@ def ticks(monkeypatch):
 def families():
     out = {}
     for key, name in (("paged", "gemma_2b"), ("ssm", "mamba2_370m"),
-                      ("rwkv6", "rwkv6_7b")):
+                      ("rwkv6", "rwkv6_7b"), ("mla", "minicpm3_4b"),
+                      ("ssm_tiles", "mamba2_370m")):
         cfg = get_config(name).reduced()
+        if key == "ssm_tiles":        # 128-wide SSD state: layered pool
+            cfg = dataclasses.replace(
+                cfg, ssm=dataclasses.replace(cfg.ssm, state_dim=128))
         model = build_model(cfg)
         params, _ = model.init(jax.random.PRNGKey(7))
         out[key] = (cfg, model, params)
@@ -238,6 +243,28 @@ def test_translation_counts_on_the_step_spans(family, families):
         assert recs["serve.grow"]["blocks"] == want
         decodes += 1
     assert decodes > 0 and eng.table_sync_bytes > 0
+    assert_engine_quiescent(eng)
+
+
+@pytest.mark.parametrize("family,layered", [("ssm_tiles", 1), ("ssm", 0),
+                                            ("rwkv6", 0), ("mla", 0)])
+def test_decode_span_says_whether_state_is_updated_in_place(
+        family, layered, families):
+    """``serve.decode`` carries ``layered``: 1 on every decode of a
+    Mamba-2 state updated in place layer by layer, 0 on a Mamba-2 state
+    too narrow for whole tiles, on RWKV6's flat state rows and on the
+    paged MLA decode."""
+    cfg, model, params = families[family]
+    eng = _engine(model, params)
+    rng = np.random.RandomState(3)
+    for rid in range(3):
+        eng.submit(_request(cfg, rid, rng, max_new=3))
+    eng.telemetry.capture(True)
+    eng.run(100)
+    decodes = [r for r in eng.telemetry.capture(False)
+               if r["name"] == "serve.decode"]
+    assert len(eng.done) == 3 and decodes
+    assert {r["layered"] for r in decodes} == {layered}
     assert_engine_quiescent(eng)
 
 

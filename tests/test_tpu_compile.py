@@ -2,15 +2,17 @@
 
 Interpret-mode kernel tests cannot see what the chip's compiler refuses
 (tiling alignment, VMEM limits, programs that do not fit HBM).  These
-tests compile, without a chip, at the shapes ``chip_smoke.py`` serves:
-the block-copy kernels at MiniCPM3-4B's latent KV pool and Mamba2-370M's
-constant-state pool, and full-width MiniCPM3-4B decode steps on one chip
-and sharded over four.
+tests compile, without a chip, at the shapes ``chip_smoke.py`` and the
+benchmark serve: the block-copy kernels at MiniCPM3-4B's latent KV pool
+and Mamba2-370M's constant-state pool, Mamba2-370M's fused decode, and
+full-width MiniCPM3-4B decode steps on one chip and sharded over four.
 
 The topology is described inside a fixture: only one process may load
 the TPU library, and the library stays loaded until that process exits,
 so nothing here may touch it while modules are imported.
 """
+
+import re
 
 import numpy as np
 import pytest
@@ -21,8 +23,10 @@ from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 from repro.configs.base import get_config
 from repro.core.paged_kv import PagedKVCache
 from repro.kernels import block_copy as bc
+from repro.kernels import ops
 from repro.launch.steps import build_serve_step
 from repro.models.api import build_model
+from repro.serve.arch import constant_state_decode_fn
 
 HBM_BYTES = 16e9                  # one v5e chip
 NB_KV = 24                        # chip_smoke's MiniCPM3-4B pool
@@ -100,16 +104,47 @@ def test_layered_block_kernels_compile(one_chip, kernel):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_block_copy_compiles_on_state_pool(one_chip):
+@pytest.mark.parametrize("layout", ["flat", "layered"])
+def test_block_copy_compiles_on_state_pool(one_chip, layout):
     """The constant-state pool's blocks are whole sequence states (52 MB
-    for Mamba2-370M): copied HBM to HBM, never staged through VMEM."""
+    for Mamba2-370M): copied HBM to HBM, never staged through VMEM, from
+    the flat ``(NB, 1, E)`` pool and from the pool laid out in rows."""
     model = build_model(get_config("mamba2_370m"))
     assert model.state_elems % 128 == 0
-    pool = _sds((NB_STATE, 1, model.state_elems), jnp.float32, one_chip)
+    block = ((1, model.state_elems) if layout == "flat"
+             else model.state_block)
+    pool = _sds((NB_STATE, *block), jnp.float32, one_chip)
     idx = _sds((N_COPY,), jnp.int32, one_chip)
     compiled = jax.jit(bc.block_copy, donate_argnums=0).lower(
         pool, idx, idx).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_mamba2_chat_decode_updates_state_in_place(one_chip, monkeypatch):
+    """The ``mamba2-chat`` cell's fused decode: Mamba2-370M at published
+    widths, 64 slots over 64 state blocks and the sink, at the widest row
+    update, with the row DMA kernels the chip runs.  Each layer's state is
+    read and written in place: under 1 GiB of temp memory (6.6 GB when
+    whole rows were gathered and relaid out), and no copy op outputs a
+    pool-shaped array."""
+    monkeypatch.setattr(ops, "_use_interpret",
+                        lambda interpret: bool(interpret))
+    model = build_model(get_config("mamba2_370m"))
+    params, _ = model.param_specs()
+    params = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), params)
+    B = 64
+    pool = _sds((B + 1, *model.state_block), jnp.float32, one_chip)
+    i32 = lambda *shape: _sds(shape, jnp.int32, one_chip)
+    compiled = constant_state_decode_fn(model).lower(
+        params, i32(B), pool, i32(B), i32(B), i32(B)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30, (
+        compiled.memory_analysis())
+    pool_type = "f32[%s]" % ",".join(map(str, pool.shape))
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.search(re.escape(pool_type) + r"\S*\s+copy(-start)?\(",
+                           line)]
+    assert not copies, copies[:3]
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_fused_decode_step_fits_one_chip(one_chip):
