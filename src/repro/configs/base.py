@@ -126,58 +126,6 @@ class ModelConfig:
             return False
         return (layer % (self.local_ratio + 1)) != self.local_ratio
 
-    def param_count(self) -> int:
-        """Approximate parameter count N (for 6ND roofline accounting)."""
-        d, L, V = self.d_model, self.num_layers, self.vocab_size
-        emb = V * d * (1 if self.tie_embeddings else 2)
-        per_layer = 0
-        if self.attention == "gqa":
-            per_layer += d * self.num_heads * self.hd * 2  # q, o
-            per_layer += d * self.kv_heads * self.hd * 2   # k, v
-        elif self.attention == "mla":
-            m = self.mla
-            qdim = self.num_heads * (m.qk_nope_head_dim + m.qk_rope_head_dim)
-            if m.q_lora_rank:
-                per_layer += d * m.q_lora_rank + m.q_lora_rank * qdim
-            else:
-                per_layer += d * qdim
-            per_layer += d * (m.kv_lora_rank + m.qk_rope_head_dim)
-            per_layer += m.kv_lora_rank * self.num_heads * (m.qk_nope_head_dim + m.v_head_dim)
-            per_layer += self.num_heads * m.v_head_dim * d
-        if self.moe is not None:
-            e = self.moe
-            moe_layer = e.num_experts * 3 * d * e.d_ff_expert + d * e.num_experts
-            moe_layer += 3 * d * e.d_ff_shared
-            dense_layer = 3 * d * self.d_ff
-            per_layer_mlp = moe_layer
-            total_mlp = (moe_layer * (L - e.first_dense_layers)
-                         + dense_layer * e.first_dense_layers)
-        elif self.ssm is not None and self.ssm.kind == "rwkv6":
-            di = d  # rwkv6 time-mix operates at d_model
-            total_mlp = L * (4 * d * di + 3 * d * self.d_ff // 1)
-        else:
-            mult = 3 if self.mlp in ("swiglu", "geglu") else 2
-            total_mlp = L * mult * d * self.d_ff
-        if self.ssm is not None and self.ssm.kind == "mamba2":
-            di = self.ssm.expand * d
-            per_layer = 2 * d * di + di * d  # in/out projections (approx)
-        total = emb + per_layer * L + total_mlp
-        if self.encoder is not None:
-            enc_layer = 4 * d * d + 3 * d * self.d_ff
-            total += self.encoder.num_layers * enc_layer
-        return int(total)
-
-    def active_param_count(self) -> int:
-        """Active params per token (MoE: top_k + shared only)."""
-        if self.moe is None:
-            return self.param_count()
-        e = self.moe
-        d, L = self.d_model, self.num_layers
-        full = self.param_count()
-        all_experts = e.num_experts * 3 * d * e.d_ff_expert * (L - e.first_dense_layers)
-        active_experts = e.top_k * 3 * d * e.d_ff_expert * (L - e.first_dense_layers)
-        return int(full - all_experts + active_experts)
-
     # -- reduced config for CPU smoke tests -----------------------------
     def reduced(self) -> "ModelConfig":
         rep = dict(

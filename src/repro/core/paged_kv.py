@@ -176,7 +176,7 @@ class PagedKVCache:
 
     @classmethod
     def specs(cls, config: PagedKVConfig, batch: int) -> "PagedKVCache":
-        """ShapeDtypeStruct stand-in for the dry-run (no allocation)."""
+        """ShapeDtypeStruct stand-in for compiling without allocating."""
         sds = jax.ShapeDtypeStruct
         k = sds(config.pool_shape(), config.dtype)
         if config.latent:

@@ -1,6 +1,6 @@
 """Instruction-level memory-traffic accounting over optimized HLO.
 
-The measurement instrument behind every number this repo reports: walks
+Static byte accounting of compiled programs: walks
 ``Compiled.as_text()`` with loop trip counts multiplied through and
 charges each executed instruction for the bytes it actually moves (see
 ``accounting`` for the rule table and ``README.md`` for the mapping to

@@ -22,8 +22,7 @@ TOUCHES:
   * ``call`` is inlining -- recurse fully; ``conditional`` takes the
     most expensive branch.
 
-Every byte lands in a category (``Cost.by_op``) so the roofline/report
-layers can show *what kind* of traffic dominates: the paper-relevant
+Every byte lands in a category (``Cost.by_op``) so a reader can see *what kind* of traffic dominates: the paper-relevant
 split is matmul vs. gather (block-table indirection) vs.
 dynamic-update-slice (block copies) vs. collective vs. everything else.
 """

@@ -1,10 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
 Dispatch policy: on TPU backends the Mosaic kernels run natively; on CPU
-(this container) ``interpret=True`` executes the kernel bodies exactly,
-and the *reference* path is what the dry-run lowers (see
-``repro.models.attention.decode_attention``).  ``force`` overrides for
-tests.
+``interpret=True`` executes the kernel bodies exactly (the reference
+path is ``repro.models.attention.decode_attention``).  ``force``
+overrides for tests.
 """
 
 from __future__ import annotations

@@ -1,8 +1,7 @@
 """Pure-jnp oracles for every Pallas kernel (no pallas imports).
 
-These are also the implementations the dry-run compiles (kernels are
-TPU-targeted; the CPU container validates them in interpret mode against
-these oracles -- see tests/test_kernels.py).
+The kernels are TPU-targeted; on the CPU they are validated in
+interpret mode against these oracles (tests/test_kernels.py).
 """
 
 from __future__ import annotations

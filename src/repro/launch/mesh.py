@@ -1,9 +1,6 @@
-"""Production mesh construction.
-
-``make_production_mesh`` is a FUNCTION (importing this module never
-touches jax device state).  Single pod: (data=16, model=16) = 256 chips
-of TPU v5e; multi-pod: (pod=2, data=16, model=16) = 512 chips, with the
-batch sharded over (pod, data) and parameters over model.
+"""Mesh construction: functions only (importing this module never
+touches jax device state).  The batch is sharded over (pod, data) and
+parameters over model.
 """
 
 from __future__ import annotations
@@ -14,15 +11,8 @@ import jax
 from jax.sharding import AxisType, Mesh
 
 
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
-
-
 def make_host_mesh(data: Optional[int] = None, model: int = 1) -> Mesh:
-    """Small mesh over however many (host) devices exist -- used by tests
-    and examples, never by the dry-run."""
+    """Small mesh over however many (host) devices exist."""
     n = jax.device_count()
     if data is None:
         data = n // model

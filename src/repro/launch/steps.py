@@ -8,8 +8,8 @@ PagedKVConfig.dp_groups), optimizer state sharded like the params
 (optionally ZeRO-1 over the data axis).
 
 The returned ``Step.lower(*specs)`` lowers under the sharding-rules
-context so ``constrain()`` calls inside the models resolve; the result
-feeds both real execution and the dry-run/roofline pipeline.
+context so ``constrain()`` calls inside the models resolve, so a step
+can be compiled for a described topology as well as run.
 """
 
 from __future__ import annotations
@@ -24,7 +24,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.configs.shapes import InputShape
 from repro.core.paged_kv import PagedKVCache
 from repro.launch import shardings as SH
 from repro.launch.mesh import batch_axes
@@ -259,18 +258,3 @@ def build_prefill_step(model, mesh: Mesh, state_example, global_batch: int, *,
         in_shardings=(pshard, None, sshard, _ns(mesh, b)),
         out_shardings=(_ns(mesh, b, rules.get("vocab", "model")), sshard))
     return Step(jitted, mesh, rules, None, None)
-
-
-def build_forward_step(model, mesh: Mesh, *, rules=None,
-                       remat: bool = False) -> Step:
-    """Forward-only (inference-prefill shape): logits + loss metrics."""
-    rules = rules_for(model.cfg, mesh, rules)
-    pshapes, axes = model.param_specs()
-    pshard = SH.param_shardings(axes, mesh, rules)
-
-    def fwd(params, batch):
-        loss, mets = model.loss(params, batch, remat=remat)
-        return loss, mets
-
-    jitted = jax.jit(fwd, in_shardings=(pshard, None))
-    return Step(jitted, mesh, rules, pshard, None)

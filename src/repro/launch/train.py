@@ -1,7 +1,6 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> ...``
 
-Runs the real fault-tolerant loop on the host devices (tests/examples) or
-lowers for the production mesh (--dry-run delegates to dryrun.py).
+Runs the real fault-tolerant loop on the host devices (tests/examples).
 Reduced configs (--reduced) make every arch runnable on CPU.
 """
 

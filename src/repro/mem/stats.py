@@ -1,7 +1,6 @@
 """ArenaStats: the observability surface of the software address space.
 
-One struct, three consumers: ``benchmarks/bench_serve.py`` embeds it in
-``BENCH_serve.json``, ``repro.report`` renders it as a table, and tests
+Returned by ``Arena.stats()`` (and ``Engine.arena_stats()``); tests
 use it for leak invariants (every engine test must end with zero
 non-pinned blocks used and an all-zero refcount histogram).
 

@@ -67,8 +67,7 @@ Shape of the plane:
     everything (or a fenced epoch-vector prefix, expanded to its
     dependency closure) now.  Token- and byte-identical behavior
     between the overlapped multi-queue schedule and the drained one is
-    pinned by the property test in ``tests/test_transfer.py`` and by
-    ``bench_serve``'s equivalence assertions.
+    pinned by the property test in ``tests/test_transfer.py``.
 
 Execution needs device arrays: clients register an *executor* per pool
 class (``register_executor``) exposing the current device streams (the
@@ -109,7 +108,7 @@ CANCELLED = "cancelled"    # speculative plan withdrawn before execution
 class UnfencedReadError(RuntimeError):
     """A block was read (table built for decode) while a transfer
     targeting it was still unfenced.  The engine's read barrier
-    (``QueueSet.dispatch`` before ``_sync_device_state``) makes this
+    (``QueueSet.dispatch`` before ``sync_device_state``) makes this
     unreachable in the step loop; reaching it means a client skipped
     the fence."""
 
@@ -193,8 +192,8 @@ def _zeroed() -> Dict[str, int]:
 
 @dataclasses.dataclass
 class TransferStats:
-    """Observability of the transfer plane (rendered by ``repro.report``
-    and embedded in ``BENCH_serve.json`` / ``BENCH_transfers.json``)."""
+    """Observability of the transfer plane (``Engine.stats["transfers"]``
+    carries its ``to_dict()``)."""
 
     enqueued: Dict[str, int] = dataclasses.field(default_factory=_zeroed)
     completed: Dict[str, int] = dataclasses.field(default_factory=_zeroed)

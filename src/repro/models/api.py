@@ -1,25 +1,19 @@
-"""Unified model API: ``build_model(cfg)`` + input/state spec builders.
+"""Unified model API: ``build_model(cfg)`` and a concrete test batch.
 
 Every model exposes:
   init(rng) -> (params, axes)        param_specs() -> (shapes, axes)
   forward(params, batch, remat=...)  loss(params, batch, remat=...)
   prefill(params, batch, state, lengths)   decode_step(params, tokens, state)
-
-``input_specs`` / ``decode_specs`` return weak-type-correct
-ShapeDtypeStruct stand-ins for the dry-run (no allocation).
 """
 
 from __future__ import annotations
 
-import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig, get_config
-from repro.configs.shapes import InputShape
-from repro.core.paged_kv import PagedKVCache
+from repro.configs.base import ModelConfig
 from repro.models.lm import DecoderLM
 from repro.models.mamba2_lm import Mamba2LM
 from repro.models.rwkv_lm import RWKVLM
@@ -37,48 +31,6 @@ def build_model(cfg: ModelConfig, max_positions: int = 4096):
     if cfg.ssm is not None and cfg.ssm.kind == "mamba2":
         return Mamba2LM(cfg)
     return DecoderLM(cfg)
-
-
-def input_specs(cfg: ModelConfig, shape: InputShape) -> Dict[str, Any]:
-    """Training/prefill batch as ShapeDtypeStructs."""
-    sds = jax.ShapeDtypeStruct
-    B, S = shape.global_batch, shape.seq_len
-    toks = S
-    batch: Dict[str, Any] = {}
-    if cfg.family == "audio":
-        batch["frames"] = sds((B, cfg.encoder.num_frames, cfg.d_model),
-                              jnp.bfloat16)
-    if cfg.num_image_tokens:
-        toks = S - cfg.num_image_tokens
-        batch["image_embeds"] = sds((B, cfg.num_image_tokens, cfg.d_model),
-                                    jnp.bfloat16)
-    batch["tokens"] = sds((B, toks), jnp.int32)
-    batch["targets"] = sds((B, toks), jnp.int32)
-    return batch
-
-
-def decode_specs(cfg: ModelConfig, shape: InputShape,
-                 model=None, dp_groups: int = 1) -> Tuple[Any, Any]:
-    """(tokens, state) ShapeDtypeStructs for serve_step lowering.
-
-    The state is sized for a KV context of ``shape.seq_len`` with the
-    paged pool exactly covering global_batch sequences.
-    """
-    model = model or build_model(cfg)
-    sds = jax.ShapeDtypeStruct
-    B, S = shape.global_batch, shape.seq_len
-    tokens = sds((B,), jnp.int32)
-    # every model describes its own decode state (no isinstance
-    # dispatch: the strategy registry in serve/arch.py relies on the
-    # same per-model surface)
-    state = model.decode_state_specs(B, S, num_blocks=_nb(cfg, S, B),
-                                     dp_groups=dp_groups)
-    return tokens, state
-
-
-def _nb(cfg: ModelConfig, S: int, B: int) -> int:
-    bt = cfg.kv_block_tokens
-    return ((S + bt - 1) // bt) * B
 
 
 def make_concrete_batch(cfg: ModelConfig, B: int, S: int, seed: int = 0):

@@ -6,9 +6,9 @@ Each layer exposes:
   fwd_kv(...)                     -> y, (k, v)          (prefill: KV out)
   decode(params, x, cache slices) -> y, new kv          (one token, paged)
 
-Decode reads the paged pool through the reference gather path (what the
-dry-run lowers); on TPU the Pallas ``paged_attention`` kernel implements
-the same contract (tests assert equality).  MLA decode uses the
+Decode reads the paged pool through the reference gather path; the
+Pallas ``paged_attention`` kernel implements the same contract (tests
+assert equality).  MLA decode uses the
 **absorbed** form: only the compressed latent stream (kv_lora + rope) is
 cached -- the paper's block-quantum argument taken to its logical end.
 """
@@ -189,7 +189,7 @@ def gqa_prefill_paged(p: Params, x: jax.Array, cfg: ModelConfig,
 
     Returns (y (B, SQ, d), (k_pool, v_pool) updated).  On TPU the Pallas
     ``kernels.paged_prefill`` kernel implements the same contract (tests
-    assert equality); this reference path is what the dry-run lowers.
+    assert equality).
     """
     B, SQ, _ = x.shape
     H, KVH, hd = cfg.num_heads, cfg.kv_heads, cfg.hd

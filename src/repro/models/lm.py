@@ -319,16 +319,6 @@ class DecoderLM:
             dtype=jnp.dtype(cfg.dtype),
             dp_groups=dp_groups)
 
-    def decode_state_specs(self, batch: int, max_seq: int,
-                           num_blocks: Optional[int] = None,
-                           dp_groups: int = 1):
-        """Shape specs of the decode-time state (dry-run surface; every
-        model exposes this so ``api.decode_specs`` never dispatches on
-        model type)."""
-        kvcfg = self.kv_config(max_seq=max_seq, num_blocks=num_blocks,
-                               batch=batch, dp_groups=dp_groups)
-        return PagedKVCache.specs(kvcfg, batch)
-
     def _write_token(self, pool_l, kv_new, tables, seq_lens, bt,
                      dp_groups: int = 1):
         return write_token_paged(pool_l, kv_new, tables, seq_lens, bt,
@@ -501,7 +491,8 @@ class DecoderLM:
         cache = dataclasses.replace(cache, block_tables=tables,
                                     seq_lens=lens)
         # Same math as decode_step: _decode_step_impl is inlined into
-        # this trace, so resident vs eager token-identity is structural.
+        # this trace, so the served decode and a decode_step reference
+        # agree token for token by construction.
         logits, cache = self._decode_step_impl(p, tokens, cache)
         return jnp.argmax(logits, axis=-1), cache
 

@@ -128,14 +128,6 @@ class Mamba2LM:
             jnp.zeros((L, batch, W - 1, d_inner + 2 * N), jnp.float32),
             jnp.zeros((L, batch, H, P, N), jnp.float32))
 
-    def state_specs(self, batch: int) -> Mamba2State:
-        return jax.eval_shape(lambda: self.init_state(batch))
-
-    def decode_state_specs(self, batch: int, max_seq: int,
-                           num_blocks: Optional[int] = None,
-                           dp_groups: int = 1):
-        return self.state_specs(batch)
-
     def prefill(self, p, batch, state: Mamba2State, lengths):
         logits, _, states = self.forward(p, batch, state=state,
                                          lengths=lengths)

@@ -31,8 +31,8 @@ from repro.models.common import AxTree, Params, dense_init
 #
 # ``jax.lax.ragged_dot`` has no grouped kernel on the CPU backend: it
 # lowers to a DENSE (tokens, E*d) x (E*d, f) contraction -- 550 GB
-# intermediates and ~20x phantom FLOPs for qwen3, which would poison the
-# dry-run roofline.  Instead we pad each expert's token run to a multiple
+# intermediates and ~20x phantom FLOPs for qwen3, which would poison any
+# compiled-HLO cost count.  Instead we pad each expert's token run to a multiple
 # of ``block`` rows inside a fixed (Tk + E*block) buffer and run ONE
 # batched (nb, m, d) x (nb, d, f) matmul with per-block expert weights --
 # the same schedule a TPU grouped-matmul kernel (megablox) executes, so

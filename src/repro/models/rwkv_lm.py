@@ -146,14 +146,6 @@ class RWKVLM:
                          jnp.zeros((L, batch, d), cfg.jdtype),
                          jnp.zeros((L, batch, H, dk, dk), jnp.float32))
 
-    def state_specs(self, batch: int) -> RWKVState:
-        return jax.eval_shape(lambda: self.init_state(batch))
-
-    def decode_state_specs(self, batch: int, max_seq: int = 0,
-                           num_blocks=None, dp_groups: int = 1):
-        """Shape specs of the decode-time state (dry-run surface)."""
-        return self.state_specs(batch)
-
     # -- constant-state pool glue (ConstantStateStrategy surface) --
     @property
     def state_elems(self) -> int:

@@ -199,13 +199,6 @@ class WhisperModel:
                       cfg.jdtype)
         return WhisperState(kv, z, z)
 
-    def decode_state_specs(self, batch: int, max_seq: int,
-                           num_blocks: Optional[int] = None,
-                           dp_groups: int = 1):
-        """Shape specs of the decode-time state (dry-run surface)."""
-        return jax.eval_shape(
-            lambda: self.init_state(batch, max_seq, num_blocks, dp_groups))
-
     def prefill(self, p, batch, state: WhisperState, lengths):
         logits, _, kv_stack = self.forward(p, batch, collect_kv=True)
         (k_self, v_self), (ke, ve) = kv_stack

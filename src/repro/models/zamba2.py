@@ -267,13 +267,6 @@ class Zamba2LM:
             rows[:, csize:].reshape(B, G, per, H, P, N), 0, 2)
         return conv, ssd
 
-    def decode_state_specs(self, batch: int, max_seq: int,
-                           num_blocks: Optional[int] = None,
-                           dp_groups: int = 1):
-        """Shape specs of the decode-time state (dry-run surface)."""
-        return jax.eval_shape(
-            lambda: self.init_state(batch, max_seq, num_blocks, dp_groups))
-
     def decode_step(self, p: Params, tokens: jax.Array, state: ZambaState):
         cfg = self.cfg
         x = p["embed"][tokens]

@@ -51,12 +51,6 @@ def init_state(params) -> AdamWState:
                       jax.tree.map(jnp.copy, zeros))
 
 
-def state_specs(param_specs) -> AdamWState:
-    sds = jax.ShapeDtypeStruct
-    zeros = jax.tree.map(lambda p: sds(p.shape, jnp.float32), param_specs)
-    return AdamWState(sds((), jnp.int32), zeros, zeros)
-
-
 def global_norm(tree) -> jax.Array:
     leaves = jax.tree.leaves(tree)
     return jnp.sqrt(sum(jnp.sum(jnp.square(l.astype(jnp.float32)))

@@ -30,7 +30,7 @@ Three disciplines:
   of whisper is not wired yet and its builder says so loudly.
 
 Per-pool-class accounting (``ArenaStats.per_class`` with per-tenant
-quota/usage) is surfaced in ``repro.report``; the scheduler's
+quota/usage) is surfaced by ``Arena.stats()``; the scheduler's
 admission, preemption and the transfer plane's per-engine holds all
 route through the strategy's view (``footprint`` / ``free_by_class`` /
 ``growing_classes`` / ``quota_headroom``).
@@ -278,8 +278,6 @@ class CacheStrategy:
     growing_classes: frozenset
     supports_prefix_sharing = False
     supports_suffix_prefill = False
-    #: engine-set: device tables/rows are resident and delta-maintained
-    resident = False
     #: the decode reads and writes each layer's state in place
     layered = False
 
@@ -355,17 +353,7 @@ class CacheStrategy:
     def prefetch(self, rid: int) -> None:
         raise NotImplementedError
 
-    # -- per-step mechanism --
-    def sync_device_state(self, running: Dict[int, object]) -> None:
-        """Derive device tables/row indices from host truth (the read
-        barrier: every running mapping must be settled)."""
-        raise NotImplementedError
-
-    def decode(self, params, tokens):
-        """One decode step over the synced device state; returns logits."""
-        raise NotImplementedError
-
-    # -- resident delta-sync protocol --
+    # -- per-step mechanism: delta sync, then one fused decode --
     def _init_resident(self) -> None:
         """Per-instance dirty-tracking state for the delta-sync path
         (strategies call this from __init__; no super().__init__)."""
@@ -427,15 +415,10 @@ class CacheStrategy:
         return (self.slots * cache.config.max_blocks_per_seq
                 * cache.config.block_tokens)
 
-    def full_sync_cost(self) -> Tuple[int, int]:
-        """(rows, bytes) one full-rebuild sync uploads -- the eager
-        fallback's per-step cost, reported beside the delta path's."""
-        raise NotImplementedError
-
-    def sync_device_state_delta(self, running) -> Tuple[int, int]:
-        """Delta read barrier: scatter only the dirty slots' rows and
-        stash the update arrays for ``decode_resident``; returns
-        (rows_updated, bytes_staged)."""
+    def sync_device_state(self, running) -> Tuple[int, int]:
+        """The read barrier (every running mapping must be settled):
+        stage only the dirty slots' rows for ``decode_resident``;
+        returns (rows_updated, bytes_staged)."""
         raise NotImplementedError
 
     def decode_resident(self, params, tokens):
@@ -514,8 +497,7 @@ class PagedKVStrategy(CacheStrategy):
         # resident tables must START all-sink / length-0: the created
         # cache fills tables with NULL (-1), and jax scatter WRAPS
         # negative indices -- an untouched empty slot would aim every
-        # padded decode write at the pool's last block.  (Harmless for
-        # the eager fallback, which rebuilds the full table per step.)
+        # padded decode write at the pool's last block.
         self.cache = dataclasses.replace(
             self.cache,
             block_tables=jnp.full_like(self.cache.block_tables, self.sink),
@@ -620,39 +602,7 @@ class PagedKVStrategy(CacheStrategy):
         self.mgr.prefetch(rid)
 
     # -- per-step mechanism --
-    def sync_device_state(self, running) -> None:
-        """Empty slots map to the SINK block, not NULL: jax scatter
-        WRAPS negative indices, so a NULL (-1) entry would clobber the
-        pool's last block on every padded decode write."""
-        cfg = self.cache.config
-        bt = cfg.block_tokens
-        tables = np.full((self.slots, cfg.max_blocks_per_seq), self.sink,
-                         np.int32)
-        lens = np.zeros(self.slots, np.int32)
-        writes = []
-        for slot, req in running.items():
-            self.mgr.mapping(req.rid).assert_settled()
-            tbl = self.mgr.device_table(req.rid)
-            tables[slot] = tbl
-            lens[slot] = req.tokens_held
-            # the coming decode appends this slot's KV at token position
-            # tokens_held -- dirty its tail block for live migration
-            writes.append(int(tbl[req.tokens_held // bt]))
-        self.mgr.allocator.note_write(writes)
-        self.cache = dataclasses.replace(
-            self.cache, block_tables=jnp.asarray(tables),
-            seq_lens=jnp.asarray(lens))
-
-    def decode(self, params, tokens):
-        logits, self.cache = self.model.decode_step(params, tokens,
-                                                    self.cache)
-        return logits
-
-    def full_sync_cost(self):
-        mb = self.cache.config.max_blocks_per_seq
-        return self.slots, self.slots * (mb + 1) * 4
-
-    def sync_device_state_delta(self, running):
+    def sync_device_state(self, running):
         """Delta read barrier: the device table is the cached translation
         structure; only slots whose mapping changed since the last step
         re-scatter.  Live-migration write tracking stays per-step (the
@@ -804,7 +754,7 @@ class PagedKVStrategy(CacheStrategy):
             assert all(alloc.is_allocated(b) for b in tbl)
             assert lens[slot] == req.tokens_held, (slot, lens[slot],
                                                    req.tokens_held)
-        if self.resident and not self._all_dirty:
+        if not self._all_dirty:
             # resident shadow vs host truth: a missed mark_dirty hook
             # surfaces HERE, not as a silent wrong-block read
             dev = np.asarray(self.cache.block_tables)
@@ -903,7 +853,6 @@ class ConstantStateStrategy(CacheStrategy):
         self.growing_classes = frozenset()      # footprint is exact
         # padded prefill must keep the SSD chunk divisibility invariant
         self._pad = max(1, getattr(model.cfg.ssm, "chunk", 1))
-        self._rows = np.full(slots, self.sink, np.int32)
         self._init_resident()
         self._upd = None
         self._rows_dev = None           # device-resident row indices
@@ -983,27 +932,7 @@ class ConstantStateStrategy(CacheStrategy):
         self.mgr.prefetch(rid)
 
     # -- per-step mechanism --
-    def sync_device_state(self, running) -> None:
-        rows = np.full(self.slots, self.sink, np.int32)
-        for slot, req in running.items():
-            self.mgr.mapping(req.rid).assert_settled()
-            rows[slot] = self.mgr.row(req.rid)
-        # every decode scatters fresh state into every running row --
-        # dirty them all for live migration
-        self.mgr.allocator.note_write(
-            [int(r) for r in rows if r != self.sink])
-        self._rows = rows
-
-    def decode(self, params, tokens):
-        idx = jnp.asarray(self._rows, jnp.int32)
-        logits, self.mgr.pool = _state_step(self.model, params, tokens,
-                                            self.mgr.pool, idx)
-        return logits
-
-    def full_sync_cost(self):
-        return self.slots, self.slots * 4
-
-    def sync_device_state_delta(self, running):
+    def sync_device_state(self, running):
         for slot, req in running.items():
             self.mgr.mapping(req.rid).assert_settled()
         # every decode scatters fresh state into every running row
@@ -1017,7 +946,6 @@ class ConstantStateStrategy(CacheStrategy):
             upd_slots[i] = slot
             if slot in running:
                 upd_rows[i] = self.mgr.row(running[slot].rid)
-            self._rows[slot] = upd_rows[i]             # host shadow
         self._upd = (upd_slots, upd_rows)
         return len(upd), (upd_slots.nbytes + upd_rows.nbytes if W else 0)
 
@@ -1105,8 +1033,7 @@ class ConstantStateStrategy(CacheStrategy):
             m = self.mgr.mapping(req.rid)
             assert len(m) == 1 and m.placement == "device"
             assert alloc.is_allocated(m.block_ids()[0])
-        if (self.resident and not self._all_dirty
-                and self._rows_dev is not None):
+        if not self._all_dirty and self._rows_dev is not None:
             dev = np.asarray(self._rows_dev)
             for slot, req in running.items():
                 if slot in self._dirty:
@@ -1161,7 +1088,6 @@ class CompositeStrategy(CacheStrategy):
         bt = kvcfg.block_tokens
         chunk = max(1, getattr(model.cfg.ssm, "chunk", 1))
         self._pad = bt * chunk // math.gcd(bt, chunk)
-        self._rows = np.full(slots, self.state_sink, np.int32)
         # resident tables start all-sink / length-0 (see PagedKVStrategy)
         self.cache = dataclasses.replace(
             self.cache,
@@ -1244,53 +1170,7 @@ class CompositeStrategy(CacheStrategy):
         self.state_mgr.swap_in(rid)
 
     # -- per-step mechanism --
-    def sync_device_state(self, running) -> None:
-        cfg = self.cache.config
-        tables = np.full((self.slots, cfg.max_blocks_per_seq), self.sink,
-                         np.int32)
-        lens = np.zeros(self.slots, np.int32)
-        rows = np.full(self.slots, self.state_sink, np.int32)
-        bt = cfg.block_tokens
-        kv_writes = []
-        for slot, req in running.items():
-            self.mgr.mapping(req.rid).assert_settled()
-            self.state_mgr.mapping(req.rid).assert_settled()
-            tbl = self.mgr.device_table(req.rid)
-            tables[slot] = tbl
-            lens[slot] = req.tokens_held
-            rows[slot] = self.state_mgr.row(req.rid)
-            kv_writes.append(int(tbl[req.tokens_held // bt]))
-        # dirty the decode write targets for live migration: the KV
-        # tail block AND the state row of every running sequence
-        self.mgr.allocator.note_write(kv_writes)
-        self.state_mgr.allocator.note_write(
-            [int(r) for r in rows if r != self.state_sink])
-        self.cache = dataclasses.replace(
-            self.cache, block_tables=jnp.asarray(tables),
-            seq_lens=jnp.asarray(lens))
-        self._rows = rows
-
-    def decode(self, params, tokens):
-        from repro.models.zamba2 import ZambaState
-        idx = jnp.asarray(self._rows, jnp.int32)
-        conv, ssd = self.model.rows_to_state(self.state_mgr.pool[idx, 0])
-        state = ZambaState(conv, ssd, self.cache)
-        logits, new_state = self.model.decode_step(params, tokens, state)
-        self.state_mgr.pool = self.state_mgr.pool.at[idx, 0].set(
-            self.model.state_to_rows(new_state.conv, new_state.ssd))
-        # carry the advanced seq_lens forward too (PagedKVStrategy keeps
-        # the whole returned cache): between steps the device lens must
-        # equal tokens_held, which check_consistency audits
-        self.cache = dataclasses.replace(
-            self.cache, k_pool=new_state.kv.k_pool,
-            v_pool=new_state.kv.v_pool, seq_lens=new_state.kv.seq_lens)
-        return logits
-
-    def full_sync_cost(self):
-        mb = self.cache.config.max_blocks_per_seq
-        return self.slots, self.slots * (mb + 2) * 4
-
-    def sync_device_state_delta(self, running):
+    def sync_device_state(self, running):
         """Both disciplines' deltas ride ONE update-slot vector: a slot
         is dirty for its KV table and its state row together (admission,
         swap and release move both classes atomically)."""
@@ -1318,7 +1198,6 @@ class CompositeStrategy(CacheStrategy):
                 upd_tables[i] = self.mgr.device_table(req.rid)
                 upd_lens[i] = req.tokens_held
                 upd_rows[i] = self.state_mgr.row(req.rid)
-            self._rows[slot] = upd_rows[i]             # host shadow
         self._upd = (upd_slots, upd_tables, upd_lens, upd_rows)
         nbytes = (upd_slots.nbytes + upd_tables.nbytes + upd_lens.nbytes
                   + upd_rows.nbytes if W else 0)
@@ -1446,7 +1325,7 @@ class CompositeStrategy(CacheStrategy):
             assert len(tbl) * bt >= req.tokens_held
             assert lens[slot] == req.tokens_held
             assert len(self.state_mgr.mapping(req.rid)) == 1
-        if self.resident and not self._all_dirty:
+        if not self._all_dirty:
             dev = np.asarray(self.cache.block_tables)
             rows_dev = (np.asarray(self._rows_dev)
                         if self._rows_dev is not None else None)

@@ -40,8 +40,7 @@ The serving stack is four layers over one address space
     identical to the non-speculative schedule.
     ``overlap_transfers=False`` selects the synchronous ``drain()``
     fallback (prefetch off), which is token-identical and
-    byte-identical by construction (pinned in tests and
-    ``bench_serve --smoke``).
+    byte-identical by construction (pinned in tests).
 
 COW prefix sharing end-to-end (paged strategies): every admitted prompt
 registers its block-aligned prefixes in a hash map; a later prompt that
@@ -103,7 +102,6 @@ class Engine:
                  overlap_transfers: bool = True,
                  prefetch: bool = True,
                  suffix_prefill: bool = True,
-                 resident_tables: bool = True,
                  pool_prefix: str = "",
                  state_blocks: Optional[int] = None):
         self.model = model
@@ -160,14 +158,10 @@ class Engine:
                                and self.strategy.supports_prefix_sharing)
         self.suffix_prefill = (suffix_prefill
                                and self.strategy.supports_suffix_prefill)
-        # resident decode path: device tables/rows are incrementally
-        # maintained (delta scatter of dirty slots only) and the step
-        # tail runs as ONE jitted, buffer-donated callable with the
-        # next-token vector latched on device.  ``resident_tables=False``
-        # is the pinned full-rebuild fallback, mirroring the
-        # ``overlap_transfers``/``drain()`` pattern.
-        self.resident_tables = resident_tables
-        self.strategy.resident = resident_tables
+        # device tables/rows are incrementally maintained (delta scatter
+        # of dirty slots only) and the step tail runs as ONE jitted,
+        # buffer-donated callable with the next-token vector latched on
+        # device
         self._tok_dev = None           # device-latched next-token vector
         self._tok_dirty = True         # host wrote _next_tok -> re-upload
         self.host_uploads = 0          # step tails with any h2d upload
@@ -525,16 +519,6 @@ class Engine:
         self._preempt_slot(slot)
         return rid
 
-    # ---------------- device-state sync ----------------
-    def _sync_device_state(self) -> None:
-        """Derive the strategy's device tables/rows from host truth each
-        step.  This is the READ BARRIER: the decode gathers every table
-        or row entry, so every running mapping must be settled (no lease
-        still the target of an unfenced transfer) -- the strategy's
-        ``assert_settled`` raises ``UnfencedReadError`` if the dispatch
-        phase was skipped."""
-        self.strategy.sync_device_state(self.running)
-
     # ---------------- main loop ----------------
     def _grow_for_next_token(self) -> int:
         """Ensure every running seq can write this step's token; returns
@@ -713,14 +697,8 @@ class Engine:
         retire what finished."""
         uploads = 0
         with tel.span("serve.sync", lead=True) as sync_sp:
-            if self.resident_tables:
-                rows, nbytes = self.strategy.sync_device_state_delta(
-                    self.running)
-                if rows:
-                    uploads += 1
-            else:
-                self._sync_device_state()
-                rows, nbytes = self.strategy.full_sync_cost()
+            rows, nbytes = self.strategy.sync_device_state(self.running)
+            if rows:
                 uploads += 1
             self.table_rows_updated += rows
             self.table_sync_bytes += nbytes
@@ -739,24 +717,18 @@ class Engine:
                 table = self.strategy.table_tokens
                 if table is not None:
                     dec_sp.set(table_tokens=table)
-            if self.resident_tables:
-                if self._tok_dirty or self._tok_dev is None:
-                    tok_dev = jnp.asarray(self._next_tok)
-                    self._tok_dirty = False
-                    uploads += 1
-                else:
-                    # steady state: this step's inputs ARE last step's
-                    # argmax, still latched on device -- zero uploads
-                    tok_dev = self._tok_dev
-                nxt_dev = self.strategy.decode_resident(self.params, tok_dev)
-                self._tok_dev = nxt_dev
-                nxt = np.asarray(nxt_dev)   # the (B,) host crossing
-                tokens = self._next_tok     # host truth of this step's inputs
-            else:
-                tokens = jnp.asarray(self._next_tok)
+            if self._tok_dirty or self._tok_dev is None:
+                tok_dev = jnp.asarray(self._next_tok)
+                self._tok_dirty = False
                 uploads += 1
-                logits = self.strategy.decode(self.params, tokens)
-                nxt = np.asarray(jnp.argmax(logits, axis=-1))  # completion
+            else:
+                # steady state: this step's inputs ARE last step's
+                # argmax, still latched on device -- zero uploads
+                tok_dev = self._tok_dev
+            nxt_dev = self.strategy.decode_resident(self.params, tok_dev)
+            self._tok_dev = nxt_dev
+            nxt = np.asarray(nxt_dev)   # the (B,) host crossing
+            tokens = self._next_tok     # host truth of this step's inputs
         with tel.span("serve.retire"):
             self.host_uploads += uploads
             self.sched.observe_decode(dec_sp.seconds)
@@ -858,7 +830,6 @@ class Engine:
                                   / max(self.store.stats.swap_ins, 1)
                                   if self.prefetches else 0.0),
             "pool_utilization": self.strategy.utilization,
-            "resident_tables": self.resident_tables,
             "host_uploads": self.host_uploads,
             "host_uploads_per_step": (self.host_uploads
                                       / max(self.steps, 1)),

@@ -29,7 +29,7 @@ grep-enforced test pins that rule).  This module is the serving stack's
     when the resume actually lands, and ``cancel_prefetch`` writes them
     off as ``prefetch_wasted_bytes``.  The demand-swap ledger is
     therefore byte-identical between the prefetching schedule and the
-    ``drain()`` fallback (asserted by ``bench_serve --smoke``), while
+    ``drain()`` fallback (asserted in ``tests/test_serve_stack.py``), while
     the speculation's true cost stays visible.
   * ``__contains__`` / ``__len__`` are the engine-invariant views:
     residency lives in the Arena's host tier, and a sequence mid-swap

@@ -7,15 +7,13 @@ admission pacing from MEASURED wall time and is deliberately not
 deterministic across runs (live-traffic coverage lives in
 test_request_plane.py)."""
 
-import numpy as np
 import pytest
 import jax
-import jax.numpy as jnp
 
 from repro.configs.base import get_config
 from repro.models.api import build_model
 from repro.serve.engine import Engine, Request
-from conftest import assert_engine_quiescent
+from conftest import assert_engine_quiescent, greedy_reference
 
 
 @pytest.fixture(scope="module")
@@ -24,29 +22,6 @@ def setup():
     model = build_model(cfg)
     params, _ = model.init(jax.random.PRNGKey(0))
     return cfg, model, params
-
-
-def greedy_reference(model, params, prompt, max_new, max_seq=64):
-    """Single-request greedy decode via prefill + decode_step."""
-    import dataclasses
-    from repro.core.paged_kv import PagedKVCache, PagedKVManager
-    kvcfg = model.kv_config(max_seq=max_seq, batch=1)
-    cache = PagedKVCache.create(kvcfg, 1)
-    mgr = PagedKVManager(kvcfg)
-    mgr.admit(0, max_seq)
-    cache = dataclasses.replace(
-        cache, block_tables=jnp.asarray(mgr.device_table(0))[None])
-    bt = kvcfg.block_tokens
-    pad = (-len(prompt)) % bt
-    toks = jnp.asarray(np.pad(prompt, (0, pad)))[None]
-    last, cache = model.prefill(params, {"tokens": toks}, cache,
-                                jnp.asarray([len(prompt)], jnp.int32))
-    out = [int(jnp.argmax(last[0]))]
-    for _ in range(max_new - 1):
-        lg, cache = model.decode_step(params,
-                                      jnp.asarray([out[-1]]), cache)
-        out.append(int(jnp.argmax(lg[0])))
-    return out
 
 
 def test_engine_matches_reference(setup, rng):
@@ -59,7 +34,8 @@ def test_engine_matches_reference(setup, rng):
     done = eng.run(max_steps=200)
     assert len(done) == 3
     for req in sorted(done, key=lambda r: r.rid):
-        ref = greedy_reference(model, params, req.prompt, 6)
+        ref = greedy_reference(model, params, req.prompt, 6,
+                               cfg.kv_block_tokens)
         assert req.generated == ref, (req.rid, req.generated, ref)
     assert_engine_quiescent(eng)
 
@@ -96,7 +72,7 @@ def test_engine_swap_out_in(setup, rng):
     assert len(eng.preempted) == 1 and not eng.running
     done = eng.run(max_steps=100)
     assert len(done) == 1
-    ref = greedy_reference(model, params, pr, 8)
+    ref = greedy_reference(model, params, pr, 8, cfg.kv_block_tokens)
     assert done[0].generated == ref
     assert done[0].generated[: len(partial)] == partial
     assert eng.store.stats.swap_outs == 1 and eng.store.stats.swap_ins == 1
@@ -126,7 +102,8 @@ def test_engine_preempt_keys_on_admission_order(setup, rng):
     done = eng.run(max_steps=200)
     assert len(done) == 2
     for req in done:
-        ref = greedy_reference(model, params, req.prompt, 8)
+        ref = greedy_reference(model, params, req.prompt, 8,
+                               cfg.kv_block_tokens)
         assert req.generated == ref
     assert_engine_quiescent(eng)
 
@@ -149,7 +126,8 @@ def test_engine_preempt_during_extend_consistent(setup, rng):
     assert len(eng.done) == 3
     assert eng.preemptions > 0     # pressure actually fired
     for req in sorted(eng.done, key=lambda r: r.rid):
-        ref = greedy_reference(model, params, req.prompt, 12, max_seq=32)
+        ref = greedy_reference(model, params, req.prompt, 12,
+                               cfg.kv_block_tokens, max_seq=32)
         assert req.generated == ref, (req.rid, req.generated, ref)
     assert_engine_quiescent(eng)
 
@@ -172,7 +150,7 @@ def test_engine_cow_fork(setup, rng):
     assert all(eng.mgr.allocator.refcount(b) == 2 for b in shared)
     done = eng.run(max_steps=100)
     assert len(done) == 2
-    ref = greedy_reference(model, params, pr, 4)
+    ref = greedy_reference(model, params, pr, 4, cfg.kv_block_tokens)
     for req in done:
         assert req.generated == ref
     assert_engine_quiescent(eng)

@@ -4,12 +4,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro import hlo_cost
+from repro import cost as cost_model
 
 
 def _cost(fn, *specs):
     comp = jax.jit(fn).lower(*specs).compile()
-    return hlo_cost.analyze_text(comp.as_text()), comp
+    return cost_model.analyze_text(comp.as_text()), comp
 
 
 def test_scan_matmul_flops_exact():
@@ -27,7 +27,7 @@ def test_scan_matmul_flops_exact():
     assert abs(cost.flops - expected) / expected < 0.01
     # XLA's own counter misses the trip count (documents the motivation);
     # xla_cost_analysis normalizes the list-vs-dict return across versions
-    xla = hlo_cost.xla_cost_analysis(comp)
+    xla = cost_model.xla_cost_analysis(comp)
     assert xla["flops"] < 0.5 * expected
 
 
@@ -92,4 +92,4 @@ def test_dynamic_update_slice_not_overcounted():
 
 def test_shape_bytes_tuple():
     s = "(s32[], f32[4,8]{1,0}, bf16[2,2]{1,0})"
-    assert hlo_cost.shape_bytes(s) == 4 + 128 + 8
+    assert cost_model.shape_bytes(s) == 4 + 128 + 8

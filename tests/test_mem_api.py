@@ -35,7 +35,7 @@ def test_no_direct_allocator_construction_outside_mem():
         r"\b(?:BlockAllocator|BlockPool)\s*\(|\bBlockPool\.create\s*\(")
     mem_dir = REPO / "src" / "repro" / "mem"
     offenders = []
-    for root in ("src/repro", "benchmarks", "examples"):
+    for root in ("src/repro", "examples"):
         for path in sorted((REPO / root).rglob("*.py")):
             if mem_dir in path.parents:
                 continue

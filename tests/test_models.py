@@ -1,6 +1,5 @@
 """Per-arch smoke tests: reduced config, one forward + one train step on
-CPU, asserting output shapes and finiteness.  (The FULL configs are only
-exercised by the dry-run.)"""
+CPU, asserting output shapes and finiteness."""
 
 import numpy as np
 import pytest

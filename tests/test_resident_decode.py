@@ -1,19 +1,28 @@
-"""Resident decode path vs the eager full-rebuild fallback.
+"""The engine's one decode path, pinned against plain references.
 
-The engine's default decode tail keeps block tables / state rows
-device-persistent, scatters only the slots whose mapping changed
-(delta sync) and runs ONE fused, buffer-donated callable per step
-(table scatter + KV append + attention + argmax, next-token vector
-latched on device).  ``resident_tables=False`` is the pinned fallback:
-full host rebuild + separate upload every step.  These tests pin the
-two paths token-identical across everything that mutates a mapping --
-forced preemption, COW forks, external compaction, live migration, a
-fork-heavy arrival trace -- for all three cache disciplines, and pin
-the resident path's whole point: steady-state decode stops uploading.
+The decode keeps block tables / state rows device-persistent, scatters
+only the slots whose mapping changed (delta sync) and runs ONE fused,
+buffer-donated callable per step (table scatter + KV append or state
+update + argmax, next-token vector latched on device).
 
-``check_consistency()`` runs every step: in resident mode it audits the
-device-side shadow (tables/rows vs the manager's truth) and would trip
-on any mapping mutation that forgot to mark its slot dirty.
+What is pinned against what:
+
+* every request's tokens against ``greedy_reference`` (conftest): the
+  model's own ``prefill`` + ``decode_step`` on one sequence, with no
+  engine, Arena or pool -- across everything that mutates a mapping
+  (forced preemption, COW forks, external compaction, live migration,
+  a fork-heavy arrival trace) for the paged, constant-state (Mamba-2,
+  RWKV6, Mamba-2 with a layered pool) and composite disciplines;
+* the layered Mamba-2 pool against the flat ``(NB, 1, E)`` pool that
+  RWKV6 and the Zamba2 hybrid are served from: tokens, and the pool's
+  bytes after every step;
+* the batched constant-state prefill against a per-sequence
+  ``model.prefill``;
+* the delta sync's point: steady-state decode stops uploading.
+
+``check_consistency()`` runs every step: it audits the device-side
+shadow (tables/rows vs the manager's truth) and would trip on any
+mapping mutation that forgot to mark its slot dirty.
 """
 
 import dataclasses
@@ -27,7 +36,7 @@ from repro.configs.base import get_config
 from repro.models.api import build_model
 from repro.models.mamba2_lm import Mamba2LM
 from repro.serve.engine import Engine, Request
-from conftest import assert_engine_quiescent
+from conftest import assert_engine_quiescent, greedy_reference
 
 
 @pytest.fixture(scope="module")
@@ -50,12 +59,20 @@ def families():
     return out
 
 
-def _engine(model, params, resident, **kw):
+def _engine(model, params, **kw):
     kw.setdefault("slots", 2)
     kw.setdefault("max_seq", 64)
     kw.setdefault("num_blocks", 24)
-    return Engine(model, params, eos_id=-1, prefill_budget=None,
-                  resident_tables=resident, **kw)
+    return Engine(model, params, eos_id=-1, prefill_budget=None, **kw)
+
+
+def _assert_matches_reference(eng, model, params):
+    """Every finished request's tokens equal its ``greedy_reference``."""
+    q = eng.strategy.prefill_quantum
+    for req in eng.done:
+        want = greedy_reference(model, params, req.prompt, req.max_new, q,
+                                max_seq=eng.max_seq)
+        assert list(req.generated) == want, (req.rid, req.generated, want)
 
 
 def _prompts(cfg, seed, n=4, shared=True):
@@ -70,54 +87,36 @@ def _prompts(cfg, seed, n=4, shared=True):
     return out
 
 
-def _run(eng, prompts, *, max_new=6, preempt_at=3, compact=False,
-         max_steps=400):
-    for i, pr in enumerate(prompts):
-        eng.submit(Request(rid=i, prompt=pr.copy(), max_new=max_new))
-    forced = compacted = False
-    while (eng.sched.has_work or eng.running) and eng.steps < max_steps:
-        eng.step()
-        eng.check_consistency()
-        if eng.steps == preempt_at and eng.running and not forced:
-            eng.preempt_latest()
-            forced = True
-        if (compact and forced and not compacted
-                and eng.arena.fragmentation(eng.mgr.pool_class) > 0):
-            assert eng.compact_now() > 0
-            eng.check_consistency()
-            compacted = True
-    eng.sync_transfers()
-    assert forced
-    if compact:
-        assert compacted
-    return {r.rid: list(r.generated) for r in eng.done}
-
-
-@pytest.mark.parametrize("family", ["dense", "ssm", "hybrid"])
-def test_resident_matches_eager_across_preemption(families, family, rng):
-    """Per-request token identity, resident vs eager, across a forced
-    preemption round-trip (and a COW fork for the paged discipline) --
-    with strictly fewer host uploads on the resident path."""
+@pytest.mark.parametrize("family",
+                         ["dense", "ssm", "hybrid", "rwkv6", "ssm_tiles"])
+def test_decode_matches_reference_across_preemption(families, family):
+    """Per-request tokens equal the single-sequence greedy reference
+    across a forced preemption round-trip (and a COW fork for the paged
+    discipline) -- with fewer host uploads than steps."""
     cfg, model, params = families[family]
     prompts = _prompts(cfg, seed=11, shared=(family == "dense"))
-    got = {}
-    uploads = {}
-    for resident in (True, False):
-        eng = _engine(model, params, resident)
-        got[resident] = _run(eng, prompts)
-        uploads[resident] = eng.stats["host_uploads"]
-        assert len(eng.done) == len(prompts)
-        assert eng.stats["resident_tables"] is resident
-        assert_engine_quiescent(eng)
-    assert got[True] == got[False]
-    assert uploads[True] < uploads[False]
+    eng = _engine(model, params)
+    for i, pr in enumerate(prompts):
+        eng.submit(Request(rid=i, prompt=pr.copy(), max_new=6))
+    forced = False
+    while (eng.sched.has_work or eng.running) and eng.steps < 400:
+        eng.step()
+        eng.check_consistency()
+        if eng.steps == 3 and eng.running and not forced:
+            eng.preempt_latest()
+            forced = True
+    eng.sync_transfers()
+    assert forced and len(eng.done) == len(prompts)
+    _assert_matches_reference(eng, model, params)
+    assert eng.stats["host_uploads"] < eng.steps
+    assert_engine_quiescent(eng)
 
 
 def test_resident_identity_across_external_compaction(families, rng):
     """A mid-flight ``compact_now()`` rewrites every lease under the
-    resident tables' feet; the full-dirty scatter must absorb it
-    token-identically (the per-step shadow audit would catch a missed
-    invalidation)."""
+    resident tables' feet; the full-dirty scatter must absorb it, so every
+    request still decodes as the greedy reference does (the per-step
+    shadow audit would catch a missed invalidation)."""
     cfg, model, params = families["dense"]
     rng23 = np.random.RandomState(23)
     base = rng23.randint(2, cfg.vocab_size, size=16)
@@ -130,44 +129,38 @@ def test_resident_identity_across_external_compaction(families, rng):
                                rng23.randint(2, cfg.vocab_size, size=5)]),
                rng23.randint(2, cfg.vocab_size, size=5)]
     max_new = [10, 6, 6, 6, 6]
-    got = {}
-    for resident in (True, False):
-        eng = _engine(model, params, resident, slots=3, num_blocks=20,
-                      watermark=1)
-        for i, pr in enumerate(prompts):
-            eng.submit(Request(rid=i, prompt=pr.copy(),
-                               max_new=max_new[i]))
-        forced = compacted = False
-        while (eng.sched.has_work or eng.running) and eng.steps < 400:
-            eng.step()
+    eng = _engine(model, params, slots=3, num_blocks=20, watermark=1)
+    for i, pr in enumerate(prompts):
+        eng.submit(Request(rid=i, prompt=pr.copy(), max_new=max_new[i]))
+    forced = compacted = False
+    while (eng.sched.has_work or eng.running) and eng.steps < 400:
+        eng.step()
+        eng.check_consistency()
+        if eng.steps == 3 and eng.running and not forced:
+            eng.preempt_latest()
+            forced = True
+        if (forced and not compacted
+                and eng.arena.fragmentation(eng.mgr.pool_class) > 0):
+            assert eng.compact_now() > 0
             eng.check_consistency()
-            if eng.steps == 3 and eng.running and not forced:
-                eng.preempt_latest()
-                forced = True
-            if (forced and not compacted
-                    and eng.arena.fragmentation(eng.mgr.pool_class) > 0):
-                assert eng.compact_now() > 0
-                eng.check_consistency()
-                compacted = True
-        eng.sync_transfers()
-        assert forced and compacted
-        assert len(eng.done) == 5
-        got[resident] = {r.rid: list(r.generated) for r in eng.done}
-        assert eng.arena.compactions >= 1
-        assert_engine_quiescent(eng)
-    assert got[True] == got[False]
+            compacted = True
+    eng.sync_transfers()
+    assert forced and compacted
+    assert len(eng.done) == 5
+    assert eng.arena.compactions >= 1
+    _assert_matches_reference(eng, model, params)
+    assert_engine_quiescent(eng)
 
 
 def test_constant_state_compaction_rides_block_copy(families):
     """``compact_now()`` on the state pool moves whole sequence states
-    with the ``block_copy`` kernel, and every sequence decodes exactly as
-    it does without the move."""
+    with the ``block_copy`` kernel, and every sequence decodes as the
+    greedy reference does, with the move and without it."""
     cfg, model, params = families["ssm"]
     prompts = _prompts(cfg, seed=11, n=3, shared=False)
     max_new = [2, 8, 8]          # the early finisher leaves a hole
-    got = {}
     for compact in (False, True):
-        eng = _engine(model, params, True, slots=3)
+        eng = _engine(model, params, slots=3)
         for i, pr in enumerate(prompts):
             eng.submit(Request(rid=i, prompt=pr.copy(), max_new=max_new[i]))
         moved = 0
@@ -181,9 +174,9 @@ def test_constant_state_compaction_rides_block_copy(families):
         launches = eng.stats["transfers"]["kernel_launches"]
         assert launches.get("block_copy", 0) >= int(compact), launches
         assert "copy_pool_blocks" not in launches
-        got[compact] = {r.rid: list(r.generated) for r in eng.done}
+        assert len(eng.done) == len(prompts)
+        _assert_matches_reference(eng, model, params)
         assert_engine_quiescent(eng)
-    assert got[True] == got[False]
 
 
 class _FlatMamba2(Mamba2LM):
@@ -197,17 +190,16 @@ def _pool_bits(eng):
     return pool.reshape(pool.shape[0], -1).view(np.uint32)
 
 
-@pytest.mark.parametrize("resident", [True, False])
-def test_layered_state_decode_matches_flat(families, resident):
+def test_layered_state_decode_matches_flat(families):
     """The in-place, layer-by-layer decode against the flat row path,
-    step for step, fused and eager: slots join and leave (sink-padded
+    step for step: slots join and leave (sink-padded
     slots between), one sequence is swapped out and back in, one
     compaction ``block_copy`` runs.  The tokens are identical, and after
     every step so is the pool, byte for byte: live blocks, the sink and
     untouched blocks."""
     cfg, model, params = families["ssm_tiles"]
     assert model.state_elems % 1024 == 0
-    engs = [_engine(m, params, resident, slots=3, num_blocks=8)
+    engs = [_engine(m, params, slots=3, num_blocks=8)
             for m in (model, _FlatMamba2(cfg))]
     layered, flat = engs
     assert layered.strategy.layered and not flat.strategy.layered
@@ -253,7 +245,7 @@ def test_other_constant_state_models_keep_the_flat_pool(families, family):
     gets the layered pool: RWKV6 and the Zamba2 hybrid keep ``(NB, 1,
     E)`` and the row-relaying decode."""
     cfg, model, params = families[family]
-    eng = _engine(model, params, True)
+    eng = _engine(model, params)
     mgr = eng.strategy.state_mgr if family == "hybrid" else eng.mgr
     assert getattr(model, "state_block", None) is None
     assert not eng.strategy.layered
@@ -263,8 +255,8 @@ def test_other_constant_state_models_keep_the_flat_pool(families, family):
 def test_resident_migrate_live_token_identity(families, tmp_path):
     """Live migration restores into a FRESH engine whose resident
     tables have never seen these requests: the adoption path must mark
-    everything dirty and resume token-identical to an unmigrated
-    resident control."""
+    everything dirty, so every request resumes and decodes as the greedy
+    reference does."""
     from repro.serve.disagg import migrate_live
 
     cfg, model, params = families["dense"]
@@ -279,56 +271,48 @@ def test_resident_migrate_live_token_identity(families, tmp_path):
             if s == 1 and eng.running:
                 eng.preempt_latest()
 
-    control = _engine(model, params, True)
-    drive_pre(control)
-    control.run(400)
-    want = {r.rid: list(r.generated) for r in control.done}
-
-    src = _engine(model, params, True)
+    src = _engine(model, params)
     drive_pre(src)
-    dst, _ = migrate_live(src, lambda: _engine(model, params, True),
+    dst, _ = migrate_live(src, lambda: _engine(model, params),
                           str(tmp_path / "resident.npz"))
     while (dst.sched.has_work or dst.running) and dst.steps < 400:
         dst.step()
         dst.check_consistency()
     dst.sync_transfers()
-    assert {r.rid: list(r.generated) for r in dst.done} == want
+    assert len(dst.done) == len(prompts)
+    _assert_matches_reference(dst, model, params)
     assert_engine_quiescent(dst)
 
 
 def test_resident_prefixheavy_trace_matches_eager(families):
     """Fork-heavy live traffic (the ``prefixheavy`` arrival trace):
     COW forks, suffix-only prefill and continuous admission all land on
-    the delta-sync path; decodes must match the eager fallback
-    per-request."""
+    the delta-sync path; every request must decode as the greedy
+    reference does."""
     from repro.serve.traffic import make_trace
 
     cfg, model, params = families["dense"]
-    got = {}
-    for resident in (True, False):
-        eng = _engine(model, params, resident, slots=3)
-        source = make_trace("prefixheavy", 8, cfg.vocab_size, seed=3,
-                            mean_gap=2.0, tenants=2, max_new=6,
-                            prompt_cap=24)
-        n = len(source)
-        eng.serve(source, max_steps=10_000)
-        eng.sync_transfers()
-        assert len(eng.done) == n
-        assert eng.stats["prefix_hits"] > 0          # forks really happened
-        got[resident] = {r.rid: list(r.generated) for r in eng.done}
-        assert_engine_quiescent(eng)
-    assert got[True] == got[False]
+    eng = _engine(model, params, slots=3)
+    source = make_trace("prefixheavy", 8, cfg.vocab_size, seed=3,
+                        mean_gap=2.0, tenants=2, max_new=6, prompt_cap=24)
+    n = len(source)
+    eng.serve(source, max_steps=10_000)
+    eng.sync_transfers()
+    assert len(eng.done) == n
+    assert eng.stats["prefix_hits"] > 0          # forks really happened
+    _assert_matches_reference(eng, model, params)
+    assert_engine_quiescent(eng)
 
 
-def test_resident_steady_state_stops_uploading(families, rng):
+@pytest.mark.parametrize("family", ["dense", "ssm", "hybrid"])
+def test_resident_steady_state_stops_uploading(families, family, rng):
     """The headline property: once admissions settle, decode steps stop
     shipping state to the device -- no table rows (outside block-growth
-    steps) and no next-token vector (latched on device).  The eager
-    fallback pays exactly two uploads every step."""
-    cfg, model, params = families["dense"]
+    steps) and no next-token vector (latched on device)."""
+    cfg, model, params = families[family]
     pr = rng.randint(2, cfg.vocab_size, size=8)
 
-    eng = _engine(model, params, True, slots=1)
+    eng = _engine(model, params, slots=1)
     eng.submit(Request(rid=0, prompt=pr, max_new=24))
     deltas = []
     last = 0
@@ -344,12 +328,7 @@ def test_resident_steady_state_stops_uploading(families, rng):
     steady = deltas[1:]
     assert steady.count(0) > len(steady) // 2
     assert eng.stats["host_uploads_per_step"] < 1.0
-
-    eng2 = _engine(model, params, False, slots=1)
-    eng2.submit(Request(rid=0, prompt=pr, max_new=24))
-    eng2.run(100)
-    assert eng2.stats["host_uploads"] == 2 * eng2.steps
-    assert list(eng2.done[0].generated) == list(eng.done[0].generated)
+    _assert_matches_reference(eng, model, params)
 
 
 @pytest.mark.parametrize("family", ["ssm", "rwkv6", "ssm_tiles"])
@@ -360,7 +339,7 @@ def test_constant_state_batched_prefill_matches_per_sequence(families,
     per-sequence prefill, and leaves another live sequence's row and the
     sink row bit-identical."""
     cfg, model, params = families[family]
-    eng = _engine(model, params, True, slots=4, num_blocks=8)
+    eng = _engine(model, params, slots=4, num_blocks=8)
     strat = eng.strategy
     q = strat.prefill_quantum
     rng = np.random.RandomState(5)
@@ -395,31 +374,3 @@ def test_constant_state_batched_prefill_matches_per_sequence(families,
         np.testing.assert_allclose(
             got[i], np.asarray(model.state_to_rows(state))[0],
             rtol=1e-5, atol=1e-5)
-
-
-def test_report_renders_decode_path_section():
-    """BENCH_serve.json rendering: populated section AND the n/a
-    degradation contract for pre-resident snapshots."""
-    from repro.report import fmt_decode_path_table
-
-    doc = {"decode_path": {
-               "resident": {"tokens_per_s": 547.6, "completed": 9,
-                            "host_uploads_per_step": 0.667,
-                            "table_sync_bytes": 760,
-                            "table_rows_updated": 19},
-               "eager": {"tokens_per_s": 439.9, "completed": 9,
-                         "host_uploads_per_step": 2.0,
-                         "table_sync_bytes": 2592,
-                         "table_rows_updated": 72},
-               "token_identical": True},
-           "phase_time_s": {"dispatch": 0.03, "sync": 0.001,
-                            "decode": 0.09, "retire": 0.001},
-           "host_uploads_per_step": 0.7, "table_sync_bytes": 800}
-    table = fmt_decode_path_table(doc)
-    assert "| resident | 547.6 | 0.667 |" in table
-    assert "| eager | 439.9 | 2.0 |" in table
-    assert "token identical: True" in table
-    assert "step-phase wall share" in table and "decode" in table
-
-    old = fmt_decode_path_table({"tokens_per_s": 1.0})
-    assert "n/a" in old and "pre-resident-path" in old

@@ -7,21 +7,19 @@ independence pin: the paper's claim only holds if management traffic
 scales with what a sequence HOLDS, never with how big the pool is.
 """
 
-import dataclasses
-
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import get_config
-from repro.core.paged_kv import PagedKVCache, PagedKVConfig, PagedKVManager
+from repro.core.paged_kv import PagedKVConfig, PagedKVManager
 from repro.models.api import build_model
 from repro.serve.engine import Engine, Request
 from repro.serve.scheduler import (PoolGroupMismatchError, Scheduler,
                                    slot_group)
 from repro.serve.swap import HostBlockStore
-from conftest import assert_engine_quiescent
+from conftest import assert_engine_quiescent, greedy_reference
 
 
 @pytest.fixture(scope="module")
@@ -30,24 +28,6 @@ def setup():
     model = build_model(cfg)
     params, _ = model.init(jax.random.PRNGKey(0))
     return cfg, model, params
-
-
-def greedy_reference(model, params, prompt, max_new, max_seq=64):
-    kvcfg = model.kv_config(max_seq=max_seq, batch=1)
-    cache = PagedKVCache.create(kvcfg, 1)
-    mgr = PagedKVManager(kvcfg)
-    mgr.admit(0, max_seq)
-    cache = dataclasses.replace(
-        cache, block_tables=jnp.asarray(mgr.device_table(0))[None])
-    bt = kvcfg.block_tokens
-    toks = jnp.asarray(np.pad(prompt, (0, (-len(prompt)) % bt)))[None]
-    last, cache = model.prefill(params, {"tokens": toks}, cache,
-                                jnp.asarray([len(prompt)], jnp.int32))
-    out = [int(jnp.argmax(last[0]))]
-    for _ in range(max_new - 1):
-        lg, cache = model.decode_step(params, jnp.asarray([out[-1]]), cache)
-        out.append(int(jnp.argmax(lg[0])))
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -309,7 +289,8 @@ def test_cow_barrier_under_pool_exhaustion(setup, rng):
     assert len(done) == 4
     assert eng.prefix_hits >= 1
     for req in sorted(done, key=lambda r: r.rid):
-        ref = greedy_reference(model, params, req.prompt, 4, max_seq=32)
+        ref = greedy_reference(model, params, req.prompt, 4,
+                               cfg.kv_block_tokens, max_seq=32)
         assert req.generated == ref, (req.rid, req.generated, ref)
     assert_engine_quiescent(eng)
 
@@ -419,7 +400,8 @@ def test_lifo_resume_served_from_completed_prefetch(setup):
         == (st2.swap_out_bytes, st2.swap_in_bytes)
     # token-identical to the single-request greedy reference
     for req in sorted(eng.done, key=lambda r: r.rid):
-        ref = greedy_reference(model, params, req.prompt, req.max_new)
+        ref = greedy_reference(model, params, req.prompt, req.max_new,
+                               cfg.kv_block_tokens)
         assert req.generated == ref, (req.rid, req.generated, ref)
     assert_engine_quiescent(eng)
     assert_engine_quiescent(eng_sync)
@@ -514,7 +496,7 @@ def test_restart_resumes_decoding(setup, rng, tmp_path):
     eng2.restore_preempted(req)
     done = eng2.run(max_steps=200)
     assert len(done) == 1
-    ref = greedy_reference(model, params, pr, 8)
+    ref = greedy_reference(model, params, pr, 8, cfg.kv_block_tokens)
     assert done[0].generated == ref
     assert done[0].generated[: len(old.generated)] == list(old.generated)
     assert_engine_quiescent(eng2)
@@ -570,7 +552,8 @@ def test_scripted_workload_token_identical(setup, rng):
         assert nbytes == nblocks * per_block
     # token-identical to the pre-refactor engine's verified reference
     for req in sorted(eng.done, key=lambda r: r.rid):
-        ref = greedy_reference(model, params, req.prompt, req.max_new)
+        ref = greedy_reference(model, params, req.prompt, req.max_new,
+                               cfg.kv_block_tokens)
         assert req.generated == ref, (req.rid, req.generated, ref)
     assert_engine_quiescent(eng)
 
@@ -621,7 +604,8 @@ def test_suffix_prefill_fork_depth_token_identical(setup, rng):
     assert len(toks[True]) == 3
     assert toks[True] == toks[False]
     for rid, pr in enumerate(prompts):
-        ref = greedy_reference(model, params, pr, max_new[rid])
+        ref = greedy_reference(model, params, pr, max_new[rid],
+                               cfg.kv_block_tokens)
         assert toks[True][rid] == ref, (rid, toks[True][rid], ref)
     eng = engines[True]
     assert eng.prefix_hits >= 2
@@ -650,7 +634,8 @@ def test_suffix_prefill_partial_tail_alias(setup, rng):
     assert len(toks[True]) == 3
     assert toks[True] == toks[False]
     for rid, pr in enumerate(prompts):
-        ref = greedy_reference(model, params, pr, max_new[rid])
+        ref = greedy_reference(model, params, pr, max_new[rid],
+                               cfg.kv_block_tokens)
         assert toks[True][rid] == ref, (rid, toks[True][rid], ref)
     # inner shares 12 but recomputes the aliased tail block (saves 8);
     # longer's share of 20 rounds down to 16 (saves 16)
@@ -675,7 +660,8 @@ def test_suffix_prefill_sliding_window_softcap(rng):
     assert len(toks[True]) == 2
     assert toks[True] == toks[False]
     for rid, pr in enumerate(prompts):
-        ref = greedy_reference(model, params, pr, max_new[rid])
+        ref = greedy_reference(model, params, pr, max_new[rid],
+                               cfg.kv_block_tokens)
         assert toks[True][rid] == ref, (rid, toks[True][rid], ref)
     assert engines[True].prefill_tokens_saved == 24
     assert_engine_quiescent(engines[True])
@@ -713,6 +699,6 @@ def test_suffix_prefill_cow_exhaustion_resume(setup, rng):
             assert eng.prefill_tokens_saved > 0
         for req in sorted(eng.done, key=lambda r: r.rid):
             ref = greedy_reference(model, params, req.prompt, req.max_new,
-                                   max_seq=32)
+                                   cfg.kv_block_tokens, max_seq=32)
             assert req.generated == ref, (flag, req.rid, req.generated, ref)
         assert_engine_quiescent(eng)
